@@ -112,7 +112,6 @@ def oracle_sweep(sizes=None, n_sims=None):
     models plus solve-level medians under each, per size."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from repro.core import nonideal as ni_mod
     from repro.physics import nodal_effective_conductance
@@ -124,7 +123,7 @@ def oracle_sweep(sizes=None, n_sims=None):
     for n in sizes:
         rng = np.random.default_rng(n)
         g_np = rng.uniform(0.0, 0.5, (n, n)) * g0
-        with enable_x64():
+        with jax.enable_x64():
             g = jnp.asarray(g_np, dtype=jnp.float64)
             h = nodal_effective_conductance(g, 1.0)
             h_fo = ni_mod.effective_conductance(g, 1.0)

@@ -17,7 +17,7 @@ The sweep shows the whole regime map: preconditioning wins big while
 sigma x cond is small, goes indefinite beyond it (PCG stalls, GMRES
 degrades gracefully), and seed-only refinement always converges.
 
-Digital refinement runs in float64 (`jax.experimental.enable_x64`); the
+Digital refinement runs in float64 (`jax.enable_x64`); the
 programmed cascade is the same noisy analog model as everywhere else.
 """
 from __future__ import annotations
@@ -31,7 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from benchmarks.common import csv_row, save_json, timed
 from repro import hybrid
@@ -138,7 +137,7 @@ def run():
     conds = (1e1, 1e3) if SMOKE else (1e1, 1e3, 1e5)
     sigmas = (0.0, 0.05) if SMOKE else (0.0, 0.02, 0.05)
     wires = (0.0,) if SMOKE else (0.0, 1.0)
-    with enable_x64():
+    with jax.enable_x64():
         keys = jax.random.split(jax.random.PRNGKey(0), 3)
         rows = _sweep(n, conds, sigmas, wires, keys)
         headline = _headline(keys)

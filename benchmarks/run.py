@@ -31,13 +31,15 @@ import argparse
 import os
 import sys
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 
 from benchmarks import (common, distributed_solver, engine_bench,
                         fig6_accuracy, fig7_variation, fig8_twostage,
                         fig9_interconnect, fig10_area_power, grad_bench,
                         hybrid_refinement, kernel_bench, maint_bench,
                         router_bench)
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main() -> None:
@@ -62,6 +64,7 @@ def main() -> None:
                          "oracle (repro.physics) instead of the first-order "
                          "model, at every fig9 size and column")
     args = ap.parse_args()
+    use_compile_cache(ROOT)
 
     if args.wire_oracle:
         fig9_interconnect.WIRE_ORACLE = True

@@ -149,6 +149,7 @@ import jax.numpy as jnp
 
 from repro.core import analog
 from repro.core.analog import AnalogConfig, CrossbarPair, TileGrid
+from repro.core.precision import F32_DOT as _F32
 
 
 # ---------------------------------------------------------------------------
@@ -322,8 +323,9 @@ def _partition_by(a: jnp.ndarray, tree) -> Target:
     a1, a2 = a[:m, :m], a[:m, m:]
     a3, a4 = a[m:, :m], a[m:, m:]
     # Digital pre-processing of the Schur complement (paper Eq. 3).  Done in
-    # f32 here, standing in for the host preprocessor in Fig. 3.
-    a4s = a4 - a3 @ jnp.linalg.solve(a1, a2)
+    # f32 here, standing in for the host preprocessor in Fig. 3 - at full
+    # f32 on every backend (a TPU's default f32 dot is one bf16 pass).
+    a4s = a4 - jnp.matmul(a3, jnp.linalg.solve(a1, a2), precision=_F32)
     return BlockTarget(_partition_by(a1, left), a2, a3,
                        _partition_by(a4s, right), m)
 
@@ -1333,10 +1335,11 @@ def _apply_level_jnp(vals, stacks, level):
         ops_sel = (stacks[sid][lo:lo + len(idxs)]
                    if idxs == tuple(range(lo, lo + len(idxs)))
                    else stacks[sid][jnp.asarray(idxs)])
-        outs = ops_sel @ gathers                    # (L, rows, k)
+        outs = jnp.matmul(ops_sel, gathers, precision=_F32)  # (L, rows, k)
         tile_outs = [outs[pos] for pos in range(len(level))]
     else:
-        tile_outs = [stacks[sid][idx] @ _slot_gather(vals, segments)
+        tile_outs = [jnp.matmul(stacks[sid][idx],
+                                _slot_gather(vals, segments), precision=_F32)
                      for sid, idx, _, _, _, segments in level]
     for out, (_, _, _, _, init, _) in zip(tile_outs, level):
         if init:
@@ -1424,9 +1427,11 @@ def _cascade_bwd(levels, out_spec, res, g):
             contiguous = idxs == tuple(range(lo, lo + len(idxs)))
             ops_sel = (stacks[sid][lo:lo + len(idxs)] if contiguous
                        else stacks[sid][jnp.asarray(idxs)])
-            ubars = jnp.swapaxes(ops_sel, -1, -2) @ cps      # (L, cols, k)
-            wbars = (cps @ jnp.swapaxes(gathers, -1, -2)
-                     ).astype(stacks[sid].dtype)             # (L, rows, cols)
+            ubars = jnp.matmul(jnp.swapaxes(ops_sel, -1, -2), cps,
+                               precision=_F32)               # (L, cols, k)
+            wbars = jnp.matmul(cps, jnp.swapaxes(gathers, -1, -2),
+                               precision=_F32
+                               ).astype(stacks[sid].dtype)   # (L, rows, cols)
             stack_bars[sid] = (
                 stack_bars[sid].at[lo:lo + len(idxs)].add(wbars) if contiguous
                 else stack_bars[sid].at[jnp.asarray(idxs)].add(wbars))
@@ -1438,8 +1443,11 @@ def _cascade_bwd(levels, out_spec, res, g):
                 cp = c[out_local:out_local + rows]
                 gat = _slot_gather(vals, segments)
                 stack_bars[sid] = stack_bars[sid].at[idx].add(
-                    (cp @ gat.T).astype(stacks[sid].dtype))
-                _scatter_ct(cot, vals, segments, stacks[sid][idx].T @ cp)
+                    jnp.matmul(cp, gat.T, precision=_F32
+                               ).astype(stacks[sid].dtype))
+                _scatter_ct(cot, vals, segments,
+                            jnp.matmul(stacks[sid][idx].T, cp,
+                                       precision=_F32))
     b_bar = cot.get(0)
     if b_bar is None:
         b_bar = jnp.zeros_like(vals[0])
@@ -2425,14 +2433,12 @@ def execute_arena_packed_sharded(pp: PackedArenaPlan, bs: jnp.ndarray,
 
 @partial(jax.jit, static_argnames=("mesh", "axis_name", "use_kernel"))
 def _sharded_packed_executor(pp, bs, mesh, axis_name, use_kernel):
-    from jax.experimental.shard_map import shard_map
-
     from repro.sharding.partition import mc_packed_specs
 
     in_specs, out_specs = mc_packed_specs(pp, axis_name)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda p, b: execute_arena_packed(p, b, use_kernel=use_kernel),
-        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return mapped(pp, bs)
 
 
@@ -2490,14 +2496,12 @@ def _sharded_mc_executor(parts: PartitionedSystem, b: jnp.ndarray,
                          keys: jax.Array, cfg: AnalogConfig, mesh,
                          axis_name: str, mode: str) -> jnp.ndarray:
     """shard_map executor; cfg/mesh/axis are static so jit caches per combo."""
-    from jax.experimental.shard_map import shard_map
-
     from repro.sharding.partition import mc_solve_specs
 
     in_specs, out_specs = mc_solve_specs(axis_name)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda p, bb, kk: _mc_execute(p, bb, kk, cfg, mode),
-        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return mapped(parts, b, keys)
 
 

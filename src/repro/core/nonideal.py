@@ -30,6 +30,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.precision import F32_DOT
+
 
 # ---------------------------------------------------------------------------
 # Conductance variation
@@ -72,14 +74,20 @@ def effective_conductance(g: jnp.ndarray, r_seg: float) -> jnp.ndarray:
     """
     if isinstance(r_seg, (int, float)) and r_seg == 0.0:
         return g
+    return g - r_seg * _ir_drop(g)
+
+
+def _ir_drop(g: jnp.ndarray) -> jnp.ndarray:
+    """G .* (C @ G) + G .* (G @ S): the first-order IR-drop term per unit
+    segment resistance (see `effective_conductance`), both dots at F32_DOT."""
     n_rows, n_cols = g.shape
     dtype = g.dtype
     i = jnp.arange(n_rows, dtype=dtype)
     j = jnp.arange(n_cols, dtype=dtype)
     c_bl = 1.0 + jnp.minimum(i[:, None], i[None, :])
     s_wl = n_cols - jnp.maximum(j[:, None], j[None, :])
-    drop = g * (c_bl @ g) + g * (g @ s_wl)
-    return g - r_seg * drop
+    return (g * jnp.matmul(c_bl, g, precision=F32_DOT)
+            + g * jnp.matmul(g, s_wl, precision=F32_DOT))
 
 
 def compensate_conductances(g_target: jnp.ndarray, r_seg: float,
@@ -95,16 +103,9 @@ def compensate_conductances(g_target: jnp.ndarray, r_seg: float,
     """
     if r_seg == 0.0:
         return g_target
-    n_rows, n_cols = g_target.shape
-    dtype = g_target.dtype
-    i = jnp.arange(n_rows, dtype=dtype)
-    j = jnp.arange(n_cols, dtype=dtype)
-    c_bl = 1.0 + jnp.minimum(i[:, None], i[None, :])
-    s_wl = n_cols - jnp.maximum(j[:, None], j[None, :])
     g = g_target
     for _ in range(iters):
-        drop = g * (c_bl @ g) + g * (g @ s_wl)
-        g = jnp.maximum(g_target + r_seg * drop, 0.0)
+        g = jnp.maximum(g_target + r_seg * _ir_drop(g), 0.0)
     return g
 
 

@@ -249,16 +249,14 @@ def solve_refined_batched(a: jnp.ndarray, b: jnp.ndarray, keys: jax.Array,
                                    "axis_name", "mode"))
 def _refined_mc_sharded(a, parts, bt, keys, cfg, method, tol, maxiter,
                         restart, use_precond, mesh, axis_name, mode):
-    from jax.experimental.shard_map import shard_map
-
     from repro.sharding.partition import mc_refined_specs
 
     in_specs, out_specs = mc_refined_specs(axis_name)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         lambda aa, pp, bb, kk: _refined_mc(aa, pp, bb, kk, cfg, method, tol,
                                            maxiter, restart, use_precond,
                                            mode),
-        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return mapped(a, parts, bt, keys)
 
 

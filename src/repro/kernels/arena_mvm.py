@@ -30,13 +30,15 @@ so one pallas_call serves an entire fleet of matrices.
 
 On TPU the metadata arrays (offsets, signs, init flags) ride in SMEM so
 the dynamic window starts are scalar reads, and the dot hits the MXU;
-`interpret=True` (the CPU CI smoke) executes the same body in Python per
-grid step.  TPU alignment note: tile shapes and the RHS-batch dim follow
-the usual (8, 128) f32 tiling; the `ops.arena_level_apply` wrapper pads
-the batch dim, and arena offsets of production plans are multiples of the
-leaf array size (64+ on paper configs).  Compiled-mode lowering has not
-been exercised in this CPU-only container (same status as the other
-kernels in this package): interpret-mode parity is the tested contract.
+`interpret=True` (the CPU tests) executes the same body in Python per
+grid step.  TPU alignment note: the `ops` wrappers pad the RHS-batch dim
+to the 128 lanes; tile shapes and arena offsets are used as-is.  On a TPU
+v5e the kernel compiles at the paper, 512^2/128^2 and four-stage 1024^2
+plans (tests/test_tpu_compile.py), serves the paper fleet
+(chip_smoke.py), and matches the jnp path bit for bit there, also on
+plans whose window offsets are not multiples of 8 (60x60 tiles at
+offsets 120, 180).  The tile dot asks for full f32: the chip's default
+f32 dot is one bf16 pass.
 """
 from __future__ import annotations
 
@@ -45,13 +47,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces; absent/unused on CPU-only installs
-    from jax.experimental.pallas import tpu as pltpu
-    _SMEM = pltpu.SMEM
-except Exception:  # pragma: no cover - CPU container fallback
-    _SMEM = None
-
+from repro.core.precision import F32_DOT
 # The one converter model (pure jnp, so it traces inside the kernel body).
 from repro.core.quantization import quantize as _quantize
 
@@ -94,7 +92,7 @@ def _arena_packed_kernel(in_offs_ref, in_signs_ref, out_offs_ref,
 
     # (R, C) x (C, K) -> (R, K) on the MXU; sign/divisor pre-folded in ops.
     out = jax.lax.dot_general(
-        ops_ref[0, 0], v, (((1,), (0,)), ((), ())),
+        ops_ref[0, 0], v, (((1,), (0,)), ((), ())), precision=F32_DOT,
         preferred_element_type=jnp.float32)
     out = _quantize(out, adc_bits, fullscale)
 
@@ -135,7 +133,7 @@ def arena_packed_apply(arena: jnp.ndarray, ops: jnp.ndarray,
     kernel = functools.partial(
         _arena_packed_kernel, rows=rows, cols=cols, n_terms=n_terms,
         dac_bits=dac_bits, adc_bits=adc_bits, fullscale=fullscale)
-    smem = {} if interpret or _SMEM is None else {"memory_space": _SMEM}
+    smem = {} if interpret else {"memory_space": pltpu.SMEM}
     meta = pl.BlockSpec(in_offs.shape, lambda i, t: (0, 0), **smem)
     flat = pl.BlockSpec((t_steps,), lambda i, t: (0,), **smem)
     inst = pl.BlockSpec((1, s, k), lambda i, t: (i, 0, 0))

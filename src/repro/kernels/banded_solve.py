@@ -12,8 +12,8 @@ pair of block-Thomas sweeps
 i.e. 2*nr dense (s x s) @ (s x k) matmuls per crossbar with a sequential
 carry.  This kernel runs them for a whole batch in one pallas_call: the
 grid walks the batch axis (one crossbar per grid step, its Minv stack and
-rhs streamed HBM->VMEM once), and the two `lax.scan`s run inside the kernel
-body on the MXU.
+rhs streamed HBM->VMEM once), and the two sweeps run inside the kernel
+body as `fori_loop`s over the block rows, on the MXU.
 
 Hybrid factor/solve split (deliberate, documented): the *factorization*
 (the Minv recursion) stays in XLA - it is irreducibly sequential in i and
@@ -23,11 +23,16 @@ zero-padding contract trivial: padded rows/columns of Minv and rhs are
 zero, zeros propagate zeros through both scans, and `ops.py` slices the
 result back.
 
-TPU alignment: ops.py pads s and k to the 128 lane width.  On CPU the
-kernel executes with interpret=True; interpret-mode parity against
+TPU alignment: ops.py pads s and k to the 128 lane width.  Each grid step
+holds one crossbar's whole (nr, s, s) factor stack and (nr, s, k) rhs and
+output blocks in VMEM, double-buffered.  The default scoped VMEM limit of a
+v5e (16 MiB) holds that only up to nr = 32 at s = k = 128, and the oracle
+runs nr = array rows (64 at the paper's arrays), so the kernel asks for
+the VMEM its blocks need (`_vmem_limit`); tests/test_tpu_compile.py
+compiles nr = 8 and 64.  On CPU the kernel executes
+with interpret=True; interpret-mode parity against
 `ref.block_tridiag_solve_ref` and the in-line jnp scans of nodal.py is the
-tested contract (tests/test_physics_oracle.py), matching every other
-kernel in this package.
+tested contract (tests/test_physics_oracle.py).
 """
 from __future__ import annotations
 
@@ -36,30 +41,46 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core.precision import F32_DOT
 
 
 def _block_tridiag_kernel(minv_ref, rhs_ref, out_ref, *, gw: float):
-    minv = minv_ref[0]                      # (nr, s, s)
-    rhs = rhs_ref[0]                        # (nr, s, k)
-    dims = (((1,), (0,)), ((), ()))         # (s,s) @ (s,k)
-    z0 = jnp.zeros(rhs.shape[1:], rhs.dtype)
+    # Both sweeps walk the refs one block row at a time with only the
+    # (s, k) carry as a loop value: Mosaic cannot lower a scan that stacks
+    # per-step outputs, so z_i lands in out_ref and the backward sweep
+    # overwrites it with x_i in place.
+    nr = rhs_ref.shape[1]
+    dtype = out_ref.dtype
+    z0 = jnp.zeros(rhs_ref.shape[2:], dtype)
 
-    def fwd(z, x):
-        mi, ri = x
-        zn = jax.lax.dot_general(mi, ri + gw * z, dims,
-                                 preferred_element_type=rhs.dtype)
-        return zn, zn
+    def dot(m, v):
+        return jax.lax.dot_general(m, v, (((1,), (0,)), ((), ())),
+                                   precision=F32_DOT,
+                                   preferred_element_type=dtype)
 
-    _, zs = jax.lax.scan(fwd, z0, (minv, rhs))
+    def fwd(i, z):
+        zn = dot(minv_ref[0, i], rhs_ref[0, i] + gw * z)
+        out_ref[0, i] = zn
+        return zn
 
-    def bwd(xn, x):
-        mi, zi = x
-        xi = zi + gw * jax.lax.dot_general(mi, xn, dims,
-                                           preferred_element_type=rhs.dtype)
-        return xi, xi
+    jax.lax.fori_loop(0, nr, fwd, z0)
 
-    _, xs = jax.lax.scan(bwd, z0, (minv[::-1], zs[::-1]))
-    out_ref[0] = xs[::-1]
+    def bwd(j, xn):
+        i = nr - 1 - j
+        xi = out_ref[0, i] + gw * dot(minv_ref[0, i], xn)
+        out_ref[0, i] = xi
+        return xi
+
+    jax.lax.fori_loop(0, nr, bwd, z0)
+
+
+def _vmem_limit(nr: int, s: int, k: int, itemsize: int) -> int:
+    """Scoped VMEM for one grid step: the Minv, rhs and output blocks,
+    double-buffered by the pipeline, plus 4 MiB for the (s, k) carries
+    and dot temporaries."""
+    return 2 * nr * s * (s + 2 * k) * itemsize + (4 << 20)
 
 
 def block_tridiag_solve(minv: jnp.ndarray, rhs: jnp.ndarray, *, gw: float,
@@ -88,5 +109,7 @@ def block_tridiag_solve(minv: jnp.ndarray, rhs: jnp.ndarray, *, gw: float,
         ],
         out_specs=pl.BlockSpec((1, nr, s, k), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, nr, s, k), rhs.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_vmem_limit(
+            nr, s, k, rhs.dtype.itemsize)),
         interpret=interpret,
     )(minv, rhs)

@@ -1,10 +1,13 @@
 """Jit'd public wrappers for the Pallas kernels: padding, dtype policy,
 CPU-interpret fallback.
 
-On a CPU host (tests, this container) `interpret=True` executes the kernel
-body in Python per grid step; on TPU the same BlockSpecs compile to Mosaic.
-The wrappers pad ragged shapes up to the 128-aligned tile grid and slice the
-result back, so callers never see the alignment constraint.
+Off a TPU (the CPU test runs) `interpret=True` executes the kernel body in
+Python per grid step; on a TPU the same BlockSpecs compile to Mosaic.
+`arena_packed_apply` and `block_tridiag_solve` have been compiled and run
+on a TPU v5e (chip_smoke.py, tests/test_tpu_compile.py); the crossbar and
+Schur kernels have only been run in interpret mode.  The wrappers pad
+ragged shapes up to the 128-aligned tile grid and slice the result back,
+so callers never see the alignment constraint.
 """
 from __future__ import annotations
 

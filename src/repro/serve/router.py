@@ -755,6 +755,11 @@ class ReplicatedSolverFleet:
         with self._lock:
             return {r.name: r.score.value() for r in self._replicas}
 
+    def replica_engines(self) -> Dict[str, AsyncSolverEngine]:
+        """Each replica's engine: its service, stats and pinned device."""
+        with self._lock:
+            return {r.name: r.engine for r in self._replicas}
+
     def maintenance_gauges(self) -> Dict[str, dict]:
         """Per-replica drift gauges (report-only observability): each
         live replica's per-matrix maintenance summary plus its scrub /

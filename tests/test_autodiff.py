@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.calib import calibrate_wire
 from repro.core import blockamc
@@ -79,7 +78,7 @@ def _spd(key, n, dtype):
 @pytest.mark.parametrize("shape", ["vec", "mat"])
 def test_grad_through_solve_matches_fd(stages, ni, shape):
     """jax.grad of w . solve(b) wrt b matches central differences."""
-    with enable_x64():
+    with jax.enable_x64():
         a = _spd(KA, N, jnp.float64)
         cfg = AnalogConfig(array_size=N, nonideal=NONIDEAL_GRID[ni])
         solver = blockamc.ProgrammedSolver.program(a, KN, cfg, stages=stages)
@@ -97,7 +96,7 @@ def test_grad_through_solve_matches_fd(stages, ni, shape):
 
 def test_grad_through_packed_executor_matches_fd():
     """The packed multi-tenant executor carries gradients per instance."""
-    with enable_x64():
+    with jax.enable_x64():
         cfg = AnalogConfig(array_size=4)
         solvers = [
             blockamc.ProgrammedSolver.program(
@@ -126,7 +125,7 @@ def test_grad_through_packed_executor_matches_fd():
 
 def test_grad_through_solve_refined_matches_analytic_adjoint():
     """IFT adjoint: d(w.x)/db = A^-T w, d(w.x)/dA = -(A^-T w) x^T."""
-    with enable_x64():
+    with jax.enable_x64():
         n = 12
         a = _spd(KA, n, jnp.float64)
         b = random_rhs(KB, n).astype(jnp.float64)
@@ -149,7 +148,7 @@ def test_grad_through_solve_refined_matches_analytic_adjoint():
 
 def test_pcg_fixed_matches_pcg_and_differentiates():
     """pcg_fixed == pcg(tol=0, maxiter=k) numerically, and grads flow."""
-    with enable_x64():
+    with jax.enable_x64():
         n = 16
         a = _spd(KA, n, jnp.float64)
         bt = jax.random.normal(KB, (3, n), jnp.float64)
@@ -183,7 +182,7 @@ def test_backward_pass_reprograms_nothing():
     """The grad jaxpr through the arena executor holds no factorization
     (`lu` runs at programming/compile time only) and no while_loop - the
     backward is one transposed cascade, ~1 forward solve."""
-    with enable_x64():
+    with jax.enable_x64():
         a = _spd(KA, N, jnp.float64)
         cfg = AnalogConfig(array_size=4, nonideal=NONIDEAL_GRID["wire"])
         solver = blockamc.ProgrammedSolver.program(a, KN, cfg)
@@ -219,7 +218,7 @@ def test_quantize_straight_through_gradient():
 def test_grad_flows_through_quantized_converters():
     """With real DAC/ADC bits the solver still yields finite, useful
     gradients (STE), where the exact derivative would be zero a.e."""
-    with enable_x64():
+    with jax.enable_x64():
         a = _spd(KA, N, jnp.float64)
         cfg = AnalogConfig(array_size=N, dac_bits=10, adc_bits=10,
                            v_fullscale=4.0)
@@ -284,7 +283,7 @@ def test_stuck_at_seed_is_sanitized_per_column():
     """A fully stuck-OFF crossbar programs a singular effective operator;
     the analog seed goes non-finite, and `solve_refined` must degrade to
     the zero seed instead of answering NaN."""
-    with enable_x64():
+    with jax.enable_x64():
         n = 8
         a = _spd(KA, n, jnp.float64)
         cfg = AnalogConfig(array_size=n, nonideal=NonidealConfig(
@@ -307,7 +306,7 @@ def test_stuck_at_seed_is_sanitized_per_column():
 
 def test_wire_grad_matches_fd():
     """d(solver output)/d(r_wire) through finalize -> arena matches FD."""
-    with enable_x64():
+    with jax.enable_x64():
         a = _spd(KA, N, jnp.float64)
         cfg = AnalogConfig(array_size=4)
         fplan = blockamc.compile_plan(blockamc.build_plan(a, KN, cfg))
@@ -328,7 +327,7 @@ def test_wire_grad_matches_fd():
 def test_wire_calibration_recovers_planted_resistance():
     """Acceptance: descend through the differentiable solver to recover a
     planted 1 Ohm from the exact nodal oracle to < 5% relative error."""
-    with enable_x64():
+    with jax.enable_x64():
         a = _spd(jax.random.fold_in(KA, 3), N, jnp.float64)
         cal = calibrate_wire(a, r_true=1.0, steps=120)
         assert cal.rel_err(1.0) < 0.05, (cal.r_hat, cal.loss)

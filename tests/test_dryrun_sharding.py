@@ -89,7 +89,6 @@ def test_grad_compression_cross_pod():
     out = run_sub("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.optim.grad_compression import compressed_psum, init_error_state
 
 mesh = jax.make_mesh((2, 4), ('pod', 'data'))
@@ -99,8 +98,8 @@ def f(g_local, err):
     total, new_err = compressed_psum({'g': g_local[0]}, 'pod', {'g': err[0]})
     return total['g'][None], new_err['g'][None]
 
-fn = shard_map(f, mesh=mesh, in_specs=(P('pod'), P('pod')),
-               out_specs=(P('pod'), P('pod')), check_rep=False)
+fn = jax.shard_map(f, mesh=mesh, in_specs=(P('pod'), P('pod')),
+               out_specs=(P('pod'), P('pod')), check_vma=False)
 err0 = jnp.zeros((2, 256))
 total, err = fn(g, err0)
 exact = jnp.sum(g, axis=0)

@@ -32,24 +32,33 @@ def test_analog_seed_saves_iterations():
     old version rebuilt the plan per call and only ever timed the digital
     path).  Richardson is the discriminating iteration: its saving is
     proportional to log(seed error), where Krylov methods barely move.
+
+    The saving must hold for every one of several programming draws, not
+    for one pinned key.  sigma=0.01 puts the n=96 seed error near 0.2
+    (measured 0.17-0.22 over five keys, a 18-29 iteration saving of 186);
+    at sigma=0.05 the seed error is ~0.8-1.0 and a seed cannot save
+    anything by construction.
     """
     a = wishart(KA, 96)
     b = random_rhs(KB, 96)
-    cfg = AnalogConfig(array_size=48, nonideal=NonidealConfig(sigma=0.05))
-    solver = blockamc.ProgrammedSolver.program(a, KN, cfg, stages=1)
-    x_seed = solver.solve(b)
-    assert float(jnp.linalg.norm(b - a @ x_seed)) > 0.0   # noisy, not exact
-    _, it_seed = hybrid.iterations_to_tol(a, b, x_seed, tol=1e-5,
-                                          method="richardson",
-                                          max_iters=20000)
+    cfg = AnalogConfig(array_size=48, nonideal=NonidealConfig(sigma=0.01))
     _, it_zero = hybrid.iterations_to_tol(a, b, jnp.zeros_like(b), tol=1e-5,
                                           method="richardson",
                                           max_iters=20000)
-    assert int(it_seed) < int(it_zero)                    # strict saving
-    # and the batched driver seeded with the same x0 agrees on convergence
-    res = hybrid.pcg(hybrid.matvec_from_dense(a), b, x0=x_seed, tol=1e-5,
-                     maxiter=500)
-    assert bool(res.converged)
+    for i in range(4):
+        solver = blockamc.ProgrammedSolver.program(
+            a, jax.random.fold_in(KN, i), cfg, stages=1)
+        x_seed = solver.solve(b)
+        assert float(jnp.linalg.norm(b - a @ x_seed)) > 0.0  # noisy, not exact
+        _, it_seed = hybrid.iterations_to_tol(a, b, x_seed, tol=1e-5,
+                                              method="richardson",
+                                              max_iters=20000)
+        assert int(it_seed) < int(it_zero), (i, int(it_seed), int(it_zero))
+        # and the batched driver seeded with the same x0 agrees on
+        # convergence
+        res = hybrid.pcg(hybrid.matvec_from_dense(a), b, x0=x_seed, tol=1e-5,
+                         maxiter=500)
+        assert bool(res.converged)
 
 
 @pytest.mark.slow
@@ -57,8 +66,7 @@ def test_refined_256_two_stage_reaches_1e10():
     """The 256^2 paper config (Fig. 8: two stages, 64^2 arrays) refined to
     full double precision: seed-only CG from the programmed analog solve
     reaches 1e-10 where the sigma=0.05 analog cascade alone cannot."""
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64():
         n = 256
         a = wishart(KA, n, dtype=jnp.float64)
         b = random_rhs(KB, n).astype(jnp.float64)

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 from tests._hypothesis_compat import HAVE_HYPOTHESIS, given, settings, st
 
 
@@ -58,7 +57,7 @@ def _positive_array(n, seed=0, nc=None, dtype=np.float64):
 @pytest.mark.parametrize("n", [2, 4, 8, 16])
 def test_mvm_parity_dense_vs_nodal(n):
     g, v = _positive_array(n, seed=n)
-    with enable_x64():
+    with jax.enable_x64():
         i_dense = nonideal.mna_mvm_currents(g, v, 1.0)
         i_nodal = np.asarray(nodal.nodal_mvm_currents(
             jnp.asarray(g), jnp.asarray(v), 1.0))
@@ -68,7 +67,7 @@ def test_mvm_parity_dense_vs_nodal(n):
 @pytest.mark.parametrize("n", [2, 4, 8, 16])
 def test_inv_parity_dense_vs_nodal(n):
     g, v = _positive_array(n, seed=100 + n)
-    with enable_x64():
+    with jax.enable_x64():
         u_dense = nonideal.mna_inv_outputs(g, v, 1.0, G0)
         u_nodal = np.asarray(nodal.nodal_inv_outputs(
             jnp.asarray(g), jnp.asarray(v), 1.0, G0))
@@ -78,7 +77,7 @@ def test_inv_parity_dense_vs_nodal(n):
 def test_parity_at_n32_both_modes():
     """The acceptance bound at the largest dense-feasible size."""
     g, v = _positive_array(32, seed=7)
-    with enable_x64():
+    with jax.enable_x64():
         np.testing.assert_allclose(
             np.asarray(nodal.nodal_mvm_currents(jnp.asarray(g),
                                                 jnp.asarray(v), 1.0)),
@@ -94,7 +93,7 @@ def test_mvm_parity_rectangular(shape):
     """The WL-elimination handles nr != nc (and degenerate 1-wide arrays)."""
     nr, nc = shape
     g, v = _positive_array(nr, seed=nr * 31 + nc, nc=nc)
-    with enable_x64():
+    with jax.enable_x64():
         np.testing.assert_allclose(
             np.asarray(nodal.nodal_mvm_currents(jnp.asarray(g),
                                                 jnp.asarray(v), 1.0)),
@@ -106,7 +105,7 @@ def test_effective_conductance_is_exact_transfer_matrix():
     H @ v reproduces the nodal currents for arbitrary drives (linearity)."""
     n = 12
     g, v = _positive_array(n, seed=3)
-    with enable_x64():
+    with jax.enable_x64():
         h = np.asarray(nodal.nodal_effective_conductance(jnp.asarray(g), 1.0))
         h_dense = np.stack(
             [nonideal.mna_mvm_currents(g, np.eye(n)[:, j], 1.0)
@@ -124,7 +123,7 @@ def test_multi_rhs_matches_column_loop():
     g, _ = _positive_array(n, seed=5)
     rng = np.random.default_rng(6)
     vs = np.abs(rng.standard_normal((n, k))) + 0.1
-    with enable_x64():
+    with jax.enable_x64():
         block = np.asarray(nodal.nodal_mvm_currents(
             jnp.asarray(g), jnp.asarray(vs), 1.0))
         for j in range(k):
@@ -255,7 +254,7 @@ def test_compensation_against_exact_mna():
 def test_property_ideal_limit(seed, n):
     """r_seg -> 0 recovers the ideal MVM g @ v."""
     g, v = _positive_array(n, seed=seed)
-    with enable_x64():
+    with jax.enable_x64():
         i = np.asarray(nodal.nodal_mvm_currents(jnp.asarray(g),
                                                 jnp.asarray(v), 1e-9))
         np.testing.assert_allclose(i, g @ v, rtol=1e-5)
@@ -284,7 +283,7 @@ def test_property_schur_blocks_spd(seed, n, r):
     """Each WL-eliminated diagonal block S_i stays symmetric positive
     definite - the invariant the block-Thomas factor relies on."""
     g, _ = _positive_array(n, seed=seed)
-    with enable_x64():
+    with jax.enable_x64():
         s = np.asarray(nodal.row_schur_blocks(jnp.asarray(g), r))
     for i in range(n):
         np.testing.assert_allclose(s[i], s[i].T, rtol=0, atol=1e-18)

@@ -21,12 +21,14 @@ def test_pipeline_matches_sequential_and_differentiates():
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.train.pipeline import pipeline_apply, split_stages
 
 S, M, B, D = 4, 8, 2, 16   # stages, microbatches, batch, width
 L = 8                      # total layers (2 per stage)
-mesh = jax.make_mesh((S,), ('pp',))
+# Auto axes: the test indexes the stage-stacked output (outs[-1]) outside
+# shard_map, which an Explicit-typed mesh refuses without an out_sharding.
+mesh = jax.make_mesh((S,), ('pp',),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 key = jax.random.PRNGKey(0)
 w = jax.random.normal(key, (L, D, D)) * (0.5 / jnp.sqrt(D))
@@ -56,8 +58,8 @@ ref = seq_all(w, x)
 # ---- pipelined ----
 w_staged = split_stages(w, S)    # (S, L/S, D, D)
 
-@partial(shard_map, mesh=mesh, in_specs=(P('pp'), P(None)),
-         out_specs=P('pp'), check_rep=False)
+@partial(jax.shard_map, mesh=mesh, in_specs=(P('pp'), P(None)),
+         out_specs=P('pp'), check_vma=False)
 def pipe(w_local, x_all):
     out = pipeline_apply(lambda p, h: stage_fn(p[0], h), w_local, x_all, 'pp')
     return out[None]             # (1, M, B, D) per stage

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core import blockamc, nonideal
 from repro.core.analog import AnalogConfig
@@ -40,7 +39,7 @@ def _gap_and_effect(n, r_wire, seed=0):
     """Returns (‖H_fo − H‖/‖H − g‖, ‖H − g‖/‖g‖) in float64."""
     rng = np.random.default_rng(seed)
     g_np = rng.uniform(0.0, 0.5, (n, n)) * G0
-    with enable_x64():
+    with jax.enable_x64():
         g = jnp.asarray(g_np, dtype=jnp.float64)
         h = nodal_effective_conductance(g, r_wire)
         h_fo = nonideal.effective_conductance(g, r_wire)
