@@ -150,6 +150,7 @@ import jax.numpy as jnp
 from repro.core import analog
 from repro.core.analog import AnalogConfig, CrossbarPair, TileGrid
 from repro.core.precision import F32_DOT as _F32
+from repro.runtime import tracing
 
 
 # ---------------------------------------------------------------------------
@@ -2465,7 +2466,18 @@ def _mc_execute(parts: PartitionedSystem, b: jnp.ndarray, keys: jax.Array,
     return jax.vmap(lambda fp: execute_flat(fp, b, cfg))(fplans)
 
 
-@partial(jax.jit, static_argnames=("cfg", "stages", "mode"))
+def _solve_batched(a, b, keys, cfg, stages, mode):
+    parts = partition_system(a, cfg, stages)
+    return _mc_execute(parts, b, keys, cfg, mode)
+
+
+# the compiled program keeps the public name (`jit_solve_batched`): its
+# persistent-cache key and the device trace's module name stay put
+_solve_batched.__name__ = _solve_batched.__qualname__ = "solve_batched"
+_solve_batched = jax.jit(_solve_batched,
+                         static_argnames=("cfg", "stages", "mode"))
+
+
 def solve_batched(a: jnp.ndarray, b: jnp.ndarray, keys: jax.Array,
                   cfg: AnalogConfig, stages: Optional[int] = None,
                   mode: str = "reference") -> jnp.ndarray:
@@ -2486,9 +2498,15 @@ def solve_batched(a: jnp.ndarray, b: jnp.ndarray, keys: jax.Array,
       keys: (num_keys, ...) PRNG keys, one independent device-noise draw each.
     Returns:
       (num_keys, n) or (num_keys, n, k) solutions.
+
+    The `blockamc.solve_batched` span times the host's part of a call:
+    argument handling and the program's dispatch, not the device's work.
     """
-    parts = partition_system(a, cfg, stages)
-    return _mc_execute(parts, b, keys, cfg, mode)
+    sp = tracing.span("blockamc.solve_batched")
+    if sp:
+        sp.attrs["draws"] = keys.shape[0]
+    with sp:
+        return _solve_batched(a, b, keys, cfg, stages, mode)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "axis_name", "mode"))

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.program_store import CheckpointRejectedError
+from repro.runtime import tracing
 from repro.runtime.chaos import HotBlock, ReplicaDeathError
 from repro.runtime.fault_tolerance import StepWatchdog, retry_step
 from repro.serve.maintenance import MaintenanceConfig, MatrixMaintenance
@@ -590,15 +591,21 @@ class AsyncSolverEngine:
         self.stats.straggles += 1
         log.warning("straggling dispatch: %.3fs (median %.3fs)", dt, median)
 
-    def _bucket_due(self, q: List[_Request], now: float) -> bool:
+    def _bucket_due(self, q: List[_Request], now: float) -> Optional[str]:
+        """The trigger that makes the bucket due ("force", "size", "age"
+        or "deadline"), or None."""
         if not q:
-            return False
-        if self._force_flush or len(q) >= self.max_batch:
-            return True
+            return None
+        if self._force_flush:
+            return "force"
+        if len(q) >= self.max_batch:
+            return "size"
         if now - q[0].t_submit >= self.flush_interval:
-            return True
-        return any(r.deadline is not None
-                   and r.deadline - now <= self.deadline_margin for r in q)
+            return "age"
+        if any(r.deadline is not None
+               and r.deadline - now <= self.deadline_margin for r in q):
+            return "deadline"
+        return None
 
     def _next_wakeup(self, now: float) -> Optional[float]:
         """Seconds until the earliest time/deadline trigger, None = idle."""
@@ -619,26 +626,31 @@ class AsyncSolverEngine:
         while True:
             with self._work:
                 now = time.monotonic()
+                idle = tracing.NO_SPAN
                 while (self._running and not self._control
                        and not any(self._bucket_due(q, now)
                                    for q in self._queues.values())
                        and not self._maint_due()):
+                    if not idle:
+                        idle = tracing.span("engine.idle").__enter__()
                     self._work.wait(self._next_wakeup(now))
                     now = time.monotonic()
+                idle.__exit__(None, None, None)
                 if not self._running:
                     break
                 control = self._control
                 self._control = []
-                due: List[Tuple[tuple, List[_Request]]] = []
+                due: List[Tuple[List[_Request], str]] = []
                 for sig, q in self._queues.items():
-                    if self._bucket_due(q, now):
-                        due.append((sig, q))
+                    trigger = self._bucket_due(q, now)
+                    if trigger:
+                        due.append((q, trigger))
                         self._queues[sig] = []
                 self._force_flush = False
             for op, args, fut in control:
                 self._run_control(op, args, fut)
-            for _, reqs in due:
-                self._dispatch_cycle(reqs)
+            for reqs, trigger in due:
+                self._dispatch_cycle(reqs, trigger)
             if not control and not due:
                 # pure maintenance wakeup: the engine scrubs/repairs only
                 # on otherwise-idle cycles, so foreground traffic always
@@ -659,7 +671,7 @@ class AsyncSolverEngine:
                 by_sig.setdefault(self._matrix[r.matrix_id].sig,
                                   []).append(r)
             for reqs in by_sig.values():
-                self._dispatch_cycle(reqs)
+                self._dispatch_cycle(reqs, "drain")
         else:
             for r in leftovers:
                 r.future.set_exception(
@@ -680,9 +692,9 @@ class AsyncSolverEngine:
     # dispatch cycle (worker thread only)
     # ------------------------------------------------------------------
 
-    def _dispatch_cycle(self, reqs: List[_Request]) -> None:
+    def _dispatch_cycle(self, reqs: List[_Request], trigger: str) -> None:
         try:
-            self._dispatch_cycle_inner(reqs)
+            self._dispatch_cycle_inner(reqs, trigger)
         except ReplicaDeathError:
             # hard replica death is NOT contained: the worker dies with
             # these futures unresolved (the fleet replays them), exactly
@@ -695,7 +707,27 @@ class AsyncSolverEngine:
                 if not r.future.done():
                     r.future.set_exception(e)
 
-    def _dispatch_cycle_inner(self, reqs: List[_Request]) -> None:
+    def _dispatch_cycle_inner(self, reqs: List[_Request],
+                              trigger: str) -> None:
+        """One cycle's span names its first dispatch index, which the
+        `engine.queued` span of each of its requests shares."""
+        cycle = tracing.span("engine.cycle")
+        if cycle:
+            dispatch = self._dispatch_count
+            cycle.attrs.update(
+                dispatch=dispatch, rhs=len(reqs), trigger=trigger,
+                tenants=len({r.matrix_id for r in reqs}))
+        with cycle:
+            if cycle:
+                # t_submit is on time.monotonic: move it to perf_counter_ns
+                shift = time.perf_counter_ns() - time.monotonic_ns()
+                for r in reqs:
+                    tracing.record("engine.queued",
+                                   int(r.t_submit * 1e9) + shift, cycle.t0,
+                                   dispatch=dispatch)
+            self._dispatch_requests(reqs)
+
+    def _dispatch_requests(self, reqs: List[_Request]) -> None:
         self._cycles += 1
         now = time.monotonic()
         # 1. shed requests whose deadline already passed - no compute
@@ -737,7 +769,10 @@ class AsyncSolverEngine:
         #    isolation as the fallback when the pack itself keeps failing
         if healthy:
             try:
-                answers, attempts = self._dispatch_packed(healthy)
+                with tracing.span("engine.dispatch") as sp:
+                    answers, attempts = self._dispatch_packed(healthy)
+                    if sp:
+                        sp.attrs["attempts"] = attempts
                 self._settle_healthy(healthy, answers, attempts)
             except Exception as e:                     # noqa: BLE001
                 log.warning("packed dispatch failed after retries (%s); "
@@ -794,8 +829,12 @@ class AsyncSolverEngine:
                 tripped.append((mid, rs))
                 continue
             xs = answers[mid]
-            for j, r in enumerate(rs):
-                self._resolve(r, xs[:, j], "analog", attempts)
+            resolve = tracing.span("engine.resolve")
+            if resolve:
+                resolve.attrs["requests"] = len(rs)
+            with resolve:
+                for j, r in enumerate(rs):
+                    self._resolve(r, xs[:, j], "analog", attempts)
         for mid, rs in tripped:
             self._quarantine_and_recover(mid, rs)
 
@@ -845,7 +884,8 @@ class AsyncSolverEngine:
         return resid
 
     def _matrix_healthy(self, mid: str, st: _MatrixState) -> bool:
-        return self._canary_residual(mid, st) <= st.trip
+        with tracing.span("engine.canary"):
+            return self._canary_residual(mid, st) <= st.trip
 
     def _quarantine_and_recover(self, mid: str,
                                 replay: List[_Request]) -> None:

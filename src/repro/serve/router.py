@@ -73,6 +73,7 @@ from repro.checkpoint.ckpt import CheckpointError
 from repro.checkpoint.program_store import (CheckpointRejectedError,
                                             ProgramStore,
                                             StaleCheckpointError)
+from repro.runtime import tracing
 from repro.runtime.elastic import ElasticMesh
 from repro.serve.async_engine import (AsyncSolverEngine, EngineStoppedError,
                                       SolveResult)
@@ -382,7 +383,13 @@ class ReplicatedSolverFleet:
         The outer future NEVER hangs: it resolves with the first finite
         answer from any leg, or with a typed error once every leg has
         failed and no survivor can take a replay."""
+        with tracing.span("fleet.submit") as sp:
+            return self._submit(sp, matrix_id, b, deadline_s, hedge)
+
+    def _submit(self, sp, matrix_id, b, deadline_s, hedge) -> Future:
         with self._lock:
+            if sp:
+                sp.attrs["lock_wait_ns"] = time.perf_counter_ns() - sp.t0
             if not self._running:
                 raise FleetError("fleet is not running")
             rec = self._matrices[matrix_id]

@@ -42,6 +42,7 @@ from repro.core.blockamc import (PackedArenaPlan, ProgrammedSolver,
 from repro.hybrid import (AnalogPreconditioner,
                           solve_fallback as _solve_fallback,
                           solve_refined as _solve_refined)
+from repro.runtime import tracing
 
 
 def _require_float_dtype(name: str, arr) -> None:
@@ -414,8 +415,10 @@ class SolverService:
         """The one per-matrix raw-solve body (no state mutation), shared
         by `flush` and `flush_all`'s single-tenant/reference fallback so
         the two paths cannot drift."""
-        return self._solvers[matrix_id].solve_many(
-            self._stack_queue(matrix_id), donate=True)
+        with tracing.span("service.stage"):
+            bs = self._stack_queue(matrix_id)
+        with tracing.span("service.execute"):
+            return self._solvers[matrix_id].solve_many(bs, donate=True)
 
     def _packed_plan(self, sig: tuple,
                      ids: Tuple[str, ...]) -> PackedArenaPlan:
@@ -426,12 +429,19 @@ class SolverService:
         a different pending subset re-packs and replaces it, so the cache
         never holds more than one pack per signature (plans are immutable
         once programmed; program() invalidates)."""
-        cached = self._packs.get(sig)
-        if cached is not None and cached[0] == ids:
-            return cached[1]
-        pp = pack_arena_plans([self._solvers[mid].arena for mid in ids])
-        self._packs[sig] = (ids, pp)
-        return pp
+        sp = tracing.span("service.pack")
+        if sp:
+            sp.attrs["tenants"] = len(ids)
+        with sp:
+            cached = self._packs.get(sig)
+            hit = cached is not None and cached[0] == ids
+            if sp:
+                sp.attrs["hit"] = int(hit)
+            if hit:
+                return cached[1]
+            pp = pack_arena_plans([self._solvers[mid].arena for mid in ids])
+            self._packs[sig] = (ids, pp)
+            return pp
 
     def flush_all(self, matrix_ids=None):
         """Continuous-batching flush: answer every pending rhs of every
@@ -462,6 +472,15 @@ class SolverService:
             for mid in ids:
                 self._queues[mid]   # unknown ids raise KeyError, like solve
         pending = [mid for mid in ids if self._queues.get(mid)]
+        sp = tracing.span("service.flush_all")
+        if sp:
+            ks = [len(self._queues[mid]) for mid in pending]
+            sp.attrs.update(tenants=len(pending), rhs=sum(ks),
+                            k_pad=1 << (max(ks, default=1) - 1).bit_length())
+        with sp:
+            return self._flush_pending(pending)
+
+    def _flush_pending(self, pending: List[str]) -> Dict[str, np.ndarray]:
         buckets: Dict[tuple, List[str]] = {}
         for mid in pending:
             buckets.setdefault(self._sigs[mid], []).append(mid)
@@ -474,8 +493,10 @@ class SolverService:
                 # single-tenant / reference fallback: the same per-matrix
                 # solve body `flush` runs, staged like the packed buckets
                 for mid in bucket:
-                    staged.append(([mid], [len(self._queues[mid])],
-                                   np.asarray(self._solve_queue(mid))[None]))
+                    xs = self._solve_queue(mid)
+                    with tracing.span("service.fetch"):
+                        xs_host = np.asarray(xs)[None]
+                    staged.append(([mid], [len(self._queues[mid])], xs_host))
                 continue
             ks = [len(self._queues[mid]) for mid in bucket]
             k_max = max(ks)
@@ -484,26 +505,29 @@ class SolverService:
             # ragged tenants zero-pad to the bucket's widest queue; the
             # dtype promotes over every queued column (np.stack promotes
             # within a tenant), matching what per-matrix flushes would do
-            tenant_stacks = [np.stack(self._queues[mid], axis=1)
-                             for mid in bucket]
-            stacked = np.zeros(
-                (len(bucket), n, k_max),
-                dtype=np.result_type(*(s.dtype for s in tenant_stacks)))
-            for i, cols in enumerate(tenant_stacks):
-                stacked[i, :, :ks[i]] = cols
-            bs, _ = pad_rhs_pow2(jnp.asarray(stacked))   # (M, n, k_pad)
+            with tracing.span("service.stage"):
+                tenant_stacks = [np.stack(self._queues[mid], axis=1)
+                                 for mid in bucket]
+                stacked = np.zeros(
+                    (len(bucket), n, k_max),
+                    dtype=np.result_type(*(s.dtype for s in tenant_stacks)))
+                for i, cols in enumerate(tenant_stacks):
+                    stacked[i, :, :ks[i]] = cols
+                bs, _ = pad_rhs_pow2(jnp.asarray(stacked))  # (M, n, k_pad)
             pp = self._packed_plan(sig, tuple(bucket))
+            with tracing.span("service.execute"):
+                xs = _execute_arena_packed_donated(pp, bs)
             # one device->host transfer; per-tenant scatter below is one
             # (n, k_id) copy each, so no tenant's answer pins the whole
             # fleet buffer in memory after delivery
-            staged.append((bucket, ks,
-                           np.asarray(_execute_arena_packed_donated(pp,
-                                                                    bs))))
+            with tracing.span("service.fetch"):
+                staged.append((bucket, ks, np.asarray(xs)))
         # Phase 2 - every dispatch succeeded: commit queues and counters.
         results: Dict[str, np.ndarray] = {}
-        for bucket, ks, xs_host in staged:
-            for i, (mid, k) in enumerate(zip(bucket, ks)):
-                results[mid] = xs_host[i, :, :k].copy()
-                self._record(mid, k)
-                self._queues[mid] = []   # only drop requests once answered
+        with tracing.span("service.scatter"):
+            for bucket, ks, xs_host in staged:
+                for i, (mid, k) in enumerate(zip(bucket, ks)):
+                    results[mid] = xs_host[i, :, :k].copy()
+                    self._record(mid, k)
+                    self._queues[mid] = []   # only drop once answered
         return results
