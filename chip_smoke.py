@@ -196,9 +196,13 @@ def serve_phase(*, n, stages, array_size, tenants, rhs, replicas=1,
     for name, eng in engines.items():
         pp = blockamc.pack_arena_plans(
             [eng.service.solver(mid).arena for mid in ids])
+        on_replica = SingleDeviceSharding(eng.device)
+        idx = jax.ShapeDtypeStruct((tenants,), jnp.int32,
+                                   sharding=on_replica)
         b = jax.ShapeDtypeStruct((tenants, n, k_pad), jnp.float32,
-                                 sharding=SingleDeviceSharding(eng.device))
-        text = blockamc._execute_arena_packed_donated.lower(pp, b).as_text()
+                                 sharding=on_replica)
+        text = blockamc._execute_arena_packed_selected_donated.lower(
+            pp, idx, b).as_text()
         kernel_in_program[name] = "tpu_custom_call" in text
         if jax.default_backend() == "tpu" and not kernel_in_program[name]:
             raise AssertionError(f"{name}: served packed executor lowered "

@@ -127,7 +127,8 @@ layer adds the cross-tenant axis the per-matrix arena form lacks.
     `finalize_batched` / `compile_arena_batched`, or `program_packed`
     end to end) vmaps the per-matrix pipeline, so programming a fleet
     costs one trace; `pack_arena_plans` stacks independently programmed
-    plans (the `SolverService.flush_all` path).
+    plans (the `SolverService` resident stack, from which
+    `execute_arena_packed_selected` gathers each flush's tenants).
   * **One dispatch over (tenants x rhs).**  `execute_arena_packed` runs
     every schedule level as stacked-tile matmuls whose batch dims carry
     the instance axis (per-tenant results bit-for-bit with that tenant's
@@ -2143,10 +2144,15 @@ class ProgrammedSolver:
 #                                    stacks, (M,) scales, one shared static
 #                                    schedule / layout / window program
 #   pack_arena_plans                 stack already-compiled ArenaPlans
-#                                    (the serving flush_all path)
+#                                    (the service's resident stacks)
+#   replace_packed_instance          write one refreshed member's plan
+#                                    over its row of a resident stack
 #   execute_arena_packed             the whole fleet as stacked-tile
 #                                    matmuls; the Pallas megakernel grows
 #                                    an instance grid axis
+#   execute_arena_packed_selected    the same over an index-selected
+#                                    subset of a resident packed plan
+#                                    (the serving flush_all path)
 #
 # Stackability invariant: every *static* artifact of the compile pipeline
 # (partition split tree, bucket shapes, flat schedule, finalized windows,
@@ -2267,13 +2273,26 @@ _STACKABLE_FIELDS = ("levels", "out_spec", "arena_size", "n", "in_off",
                      "cfg", "kernel_ok", "slot_offsets")
 
 
+def _check_stackable(ap, ref) -> None:
+    """Raise ValueError unless `ap` shares `ref`'s static metadata."""
+    for f in _STACKABLE_FIELDS:
+        if getattr(ap, f) != getattr(ref, f):
+            raise ValueError(
+                f"arena plans are not stackable: static field {f!r} "
+                f"differs (plans compiled from different "
+                f"plan_signature buckets?)")
+
+
 def pack_arena_plans(aps) -> PackedArenaPlan:
     """Stack already-compiled same-signature ArenaPlans into a packed plan.
 
-    The serving `flush_all` path: each tenant's matrix was programmed (and
-    arena-compiled) independently at admission time; packing is a pure
-    leaf-for-leaf `jnp.stack` plus a static-metadata equality check, so it
-    is cheap enough to run per flush.  Raises ValueError when the plans'
+    The serving path's resident stacks: each tenant's matrix was
+    programmed (and arena-compiled) independently at admission time;
+    packing is a pure leaf-for-leaf `jnp.stack` plus a static-metadata
+    equality check.  It is a few dozen eager host dispatches, so the
+    service builds one resident stack per signature with it and selects
+    each flush's tenants by index (`execute_arena_packed_selected`)
+    instead of packing per flush.  Raises ValueError when the plans'
     static structure diverges (different `plan_signature` - they cannot
     share one schedule).
     """
@@ -2282,12 +2301,7 @@ def pack_arena_plans(aps) -> PackedArenaPlan:
         raise ValueError("pack_arena_plans needs at least one plan")
     ap0 = aps[0]
     for ap in aps[1:]:
-        for f in _STACKABLE_FIELDS:
-            if getattr(ap, f) != getattr(ap0, f):
-                raise ValueError(
-                    f"arena plans are not stackable: static field {f!r} "
-                    f"differs (plans compiled from different "
-                    f"plan_signature buckets?)")
+        _check_stackable(ap, ap0)
     stacks = tuple(jnp.stack([ap.stacks[i] for ap in aps])
                    for i in range(len(ap0.stacks)))
     scale = jnp.stack([ap.scale for ap in aps])
@@ -2299,6 +2313,31 @@ def pack_arena_plans(aps) -> PackedArenaPlan:
                            ap0.levels, ap0.out_spec, ap0.arena_size, ap0.n,
                            ap0.in_off, ap0.cfg, ap0.kernel_ok,
                            ap0.num_arrays, ap0.slot_offsets, len(aps))
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _set_instance(leaves, row, new):
+    return jax.tree_util.tree_map(lambda s, x: s.at[row].set(x), leaves, new)
+
+
+def replace_packed_instance(pp: PackedArenaPlan, row: int,
+                            ap) -> PackedArenaPlan:
+    """`pp` with instance `row` replaced by the same-signature ArenaPlan
+    `ap` - how a resident stack follows one member's refreshed plan
+    without re-stacking the rest.  One jitted program writes the row of
+    every per-instance leaf (`stacks`, `scale`, `program_ops`) in place:
+    those leaves are donated, so `pp` must not be used afterwards.  The
+    shared `program_meta` is kept as it is.  Raises ValueError, leaving
+    `pp` intact, when `ap`'s static structure differs from `pp`'s."""
+    _check_stackable(ap, pp)
+    stacks, scale, program_ops = _set_instance(
+        (pp.stacks, pp.scale, pp.program_ops), row,
+        (ap.stacks, ap.scale,
+         None if ap.program is None else ap.program[0]))
+    return PackedArenaPlan(stacks, scale, program_ops, pp.program_meta,
+                           pp.levels, pp.out_spec, pp.arena_size, pp.n,
+                           pp.in_off, pp.cfg, pp.kernel_ok, pp.num_arrays,
+                           pp.slot_offsets, pp.num_instances)
 
 
 def compile_arena_batched(fins: FinalizedPlan) -> PackedArenaPlan:
@@ -2403,9 +2442,37 @@ def execute_arena_packed(pp: PackedArenaPlan, bs: jnp.ndarray,
 
 _execute_arena_packed = jax.jit(execute_arena_packed,
                                 static_argnames=("use_kernel",))
-_execute_arena_packed_donated = jax.jit(execute_arena_packed,
-                                        donate_argnums=(1,),
-                                        static_argnames=("use_kernel",))
+
+
+def execute_arena_packed_selected(pp: PackedArenaPlan, idx: jnp.ndarray,
+                                  bs: jnp.ndarray,
+                                  use_kernel: Optional[bool] = None
+                                  ) -> jnp.ndarray:
+    """`execute_arena_packed` over the instances `idx` of a resident plan.
+
+    `idx` is an (M,) int32 vector of instance rows of `pp`, in the order
+    of `bs`'s (M, n[, k]) right-hand sides; the result's row i answers
+    instance `idx[i]`.  Jitted (`_execute_arena_packed_selected_donated`),
+    the gather and the executor are one program, compiled once per
+    (M, k) whichever rows `idx` names - the serving `flush_all` path over
+    the service's resident per-signature stack.  Only the per-instance
+    leaves (`stacks`, `scale`, `program_ops`) are gathered; XLA drops the
+    gather of any leaf the chosen path does not read.
+    """
+    def take(x):
+        return None if x is None else x[idx]
+
+    sel = PackedArenaPlan(
+        tuple(take(s) for s in pp.stacks), take(pp.scale),
+        take(pp.program_ops), pp.program_meta, pp.levels, pp.out_spec,
+        pp.arena_size, pp.n, pp.in_off, pp.cfg, pp.kernel_ok,
+        pp.num_arrays, pp.slot_offsets, idx.shape[0])
+    return execute_arena_packed(sel, bs, use_kernel=use_kernel)
+
+
+_execute_arena_packed_selected_donated = jax.jit(
+    execute_arena_packed_selected, donate_argnums=(2,),
+    static_argnames=("use_kernel",))
 
 
 def execute_arena_packed_sharded(pp: PackedArenaPlan, bs: jnp.ndarray,

@@ -9,14 +9,16 @@ solved in one fused `solve_many` call instead of one cascade walk each.
 Multi-tenant packing: `flush_all` is the cross-matrix analogue of the
 per-matrix flush.  Pending queues are grouped by `plan_signature` (the
 structural stackability key - see the packed-serving DESIGN note in
-core/blockamc.py), each bucket's arena plans are packed leaf-for-leaf on a
-leading instance axis (cached per id-set; the plans themselves are
-immutable once programmed), ragged per-tenant queue lengths are zero-padded
-to one shared power-of-two rhs width via `pad_rhs_pow2`, and the whole
-bucket dispatches as ONE `execute_arena_packed` call instead of one
-dispatch per tenant.  Answers scatter back per tenant, and per-tenant
-counters go through the single `_record` bookkeeping helper so packed
-solves are never double-counted.
+core/blockamc.py).  Every programmed tenant of a signature sits in one
+resident packed plan (arena plans stacked leaf-for-leaf on a leading
+instance axis, built once and kept until a member's plan changes), ragged
+per-tenant queue lengths are zero-padded to one shared power-of-two rhs
+width via `pad_rhs_pow2`, and the whole bucket dispatches as ONE
+`execute_arena_packed_selected` call, which gathers the bucket's tenants
+from the resident plan by index, instead of one dispatch per tenant.
+Answers scatter back per tenant, and per-tenant counters go through the
+single `_record` bookkeeping helper so packed solves are never
+double-counted.
 
 Deliberately synchronous and small - the batching discipline and the
 program/solve cost split are the point; transport and scheduling live a
@@ -36,9 +38,9 @@ import numpy as np
 
 from repro.core.analog import AnalogConfig
 from repro.core.blockamc import (PackedArenaPlan, ProgrammedSolver,
-                                 _execute_arena_packed_donated,
+                                 _execute_arena_packed_selected_donated,
                                  pack_arena_plans, pad_rhs_pow2,
-                                 plan_signature)
+                                 plan_signature, replace_packed_instance)
 from repro.hybrid import (AnalogPreconditioner,
                           solve_fallback as _solve_fallback,
                           solve_refined as _solve_refined)
@@ -88,12 +90,14 @@ class SolverService:
         self._stats: Dict[str, MatrixStats] = {}
         self._sigs: Dict[str, tuple] = {}
         self._cfgs: Dict[str, AnalogConfig] = {}   # per-matrix cfg override
-        # packed cross-tenant plans: one cached (id tuple, pack) per
-        # signature - the cache is bounded by the number of signatures,
-        # not by the 2^M possible pending subsets.  A flush whose bucket
-        # membership changed re-packs and replaces the entry; program()
-        # invalidates entries containing the re-programmed id.
-        self._packs: Dict[tuple, Tuple[Tuple[str, ...],
+        # resident packed plans: one (rows, pack) per signature, stacking
+        # every programmed tenant of it (rows: matrix id -> instance row,
+        # in stack order).  Flushes select their pending tenants by index,
+        # so a new pending subset costs nothing and the cache is bounded
+        # by the number of signatures.  program/install of a member drops
+        # the entry, refresh writes the member's row in place, and a
+        # pending tenant the entry lacks rebuilds it.
+        self._packs: Dict[tuple, Tuple[Dict[str, int],
                                        PackedArenaPlan]] = {}
 
     def program(self, matrix_id: str, a: jnp.ndarray,
@@ -154,9 +158,7 @@ class SolverService:
             program_time_s=time.perf_counter() - t0)
         self._cfgs[matrix_id] = cfg
         self._sigs[matrix_id] = plan_signature(a.shape[0], self.stages, cfg)
-        # any cached pack containing the replaced plan is stale
-        self._packs = {sig: (ids, pp) for sig, (ids, pp)
-                       in self._packs.items() if matrix_id not in ids}
+        self._drop_packs(matrix_id)
         return solver
 
     def install(self, matrix_id: str, solver: ProgrammedSolver,
@@ -202,8 +204,7 @@ class SolverService:
             program_time_s=time.perf_counter() - t0)
         self._cfgs[matrix_id] = cfg
         self._sigs[matrix_id] = sig
-        self._packs = {s: (ids, pp) for s, (ids, pp)
-                       in self._packs.items() if matrix_id not in ids}
+        self._drop_packs(matrix_id)
         return solver
 
     def refresh(self, matrix_id: str, solver: ProgrammedSolver) -> None:
@@ -213,19 +214,31 @@ class SolverService:
         splices produce a new `ProgrammedSolver` for the SAME matrix,
         config and plan signature (drift/repair never enter
         `plan_signature`), so queues, stats, sigs and the digital copy
-        all stay - only the solver handle and any cached packed plan
-        built from its arena are replaced.  Pending right-hand sides are
-        fine: they are answered by the refreshed (healthier) solver at
-        the next flush, which is the whole point of repairing in place.
+        all stay - only the solver handle is replaced, and its row of the
+        resident packed plan is rewritten in place (one small program;
+        the other members are not re-stacked).  Pending right-hand
+        sides are fine: they are answered by the refreshed (healthier)
+        solver at the next flush, which is the whole point of repairing
+        in place.
         """
         old = self._solvers[matrix_id]          # unknown ids raise KeyError
         if solver.n != old.n:
             raise ValueError(
                 f"refresh for {matrix_id!r} changed n: {old.n} -> "
                 f"{solver.n}")
+        sig = self._sigs[matrix_id]
+        entry = self._packs.get(sig)
+        if entry is not None and matrix_id in entry[0]:
+            rows, pp = entry
+            self._packs[sig] = (rows, replace_packed_instance(
+                pp, rows[matrix_id], solver.arena))
         self._solvers[matrix_id] = solver
-        self._packs = {sig: (ids, pp) for sig, (ids, pp)
-                       in self._packs.items() if matrix_id not in ids}
+
+    def _drop_packs(self, matrix_id: str) -> None:
+        """Drop the resident packed plan holding `matrix_id` (its plan,
+        or its signature, changed)."""
+        self._packs = {sig: entry for sig, entry in self._packs.items()
+                       if matrix_id not in entry[0]}
 
     def solver(self, matrix_id: str) -> ProgrammedSolver:
         return self._solvers[matrix_id]
@@ -420,28 +433,33 @@ class SolverService:
         with tracing.span("service.execute"):
             return self._solvers[matrix_id].solve_many(bs, donate=True)
 
-    def _packed_plan(self, sig: tuple,
-                     ids: Tuple[str, ...]) -> PackedArenaPlan:
-        """The packed arena plan for one tenant bucket.
+    def _packed_plan(self, sig: tuple, bucket: List[str]
+                     ) -> Tuple[PackedArenaPlan, np.ndarray]:
+        """The resident packed plan of `sig` and the bucket's rows in it.
 
-        One entry is cached per *signature* and reused while the bucket's
-        membership is stable (the steady state of a saturated service);
-        a different pending subset re-packs and replaces it, so the cache
-        never holds more than one pack per signature (plans are immutable
-        once programmed; program() invalidates)."""
+        The resident plan stacks every programmed tenant of the signature,
+        whichever are pending, so it is reused across flushes whatever
+        subset they hold (the span's `hit`); it is rebuilt only when
+        missing (dropped by program/install of a member) or when a
+        pending tenant joined the signature after it was built."""
         sp = tracing.span("service.pack")
         if sp:
-            sp.attrs["tenants"] = len(ids)
+            sp.attrs["tenants"] = len(bucket)
         with sp:
             cached = self._packs.get(sig)
-            hit = cached is not None and cached[0] == ids
+            hit = cached is not None and all(mid in cached[0]
+                                             for mid in bucket)
             if sp:
                 sp.attrs["hit"] = int(hit)
-            if hit:
-                return cached[1]
-            pp = pack_arena_plans([self._solvers[mid].arena for mid in ids])
-            self._packs[sig] = (ids, pp)
-            return pp
+            if not hit:
+                members = [mid for mid in self._solvers
+                           if self._sigs[mid] == sig]
+                cached = ({mid: i for i, mid in enumerate(members)},
+                          pack_arena_plans([self._solvers[mid].arena
+                                            for mid in members]))
+                self._packs[sig] = cached
+            rows, pp = cached
+            return pp, np.asarray([rows[mid] for mid in bucket], np.int32)
 
     def flush_all(self, matrix_ids=None):
         """Continuous-batching flush: answer every pending rhs of every
@@ -452,14 +470,16 @@ class SolverService:
         tenant's queued columns stack to (n, k_i), ragged k_i zero-pad to
         the bucket's shared power-of-two width (`pad_rhs_pow2` - padding
         columns are zero right-hand sides and are sliced away before
-        return), the bucket packs to an (M, n, k_pad) batch and ONE
-        `execute_arena_packed` call (buffer donated, like `flush`) answers
-        the whole fleet.  Returns {matrix_id: (n, k_id) solutions}, column
-        j answering the j-th submit since the last flush; ids with empty
-        queues are omitted.  All answers come back host-resident numpy
-        (the delivery form: one device->host transfer per bucket, one
-        small owned copy per tenant - so no answer pins the fleet buffer
-        - and per-ticket column delivery is a free numpy view) -
+        return), the bucket's rhs stack to an (M, n, k_pad) batch, and ONE
+        `execute_arena_packed_selected` call (buffer donated, like
+        `flush`) gathers the bucket's M tenants from the signature's
+        resident packed plan by index and answers them all.  Returns
+        {matrix_id: (n, k_id) solutions}, column j answering the j-th
+        submit since the last flush; ids with empty queues are omitted.
+        All answers come back host-resident numpy (the delivery form: one
+        device->host transfer per bucket, one small owned copy per tenant
+        - so no answer pins the fleet buffer - and per-ticket column
+        delivery is a free numpy view) -
         uniformly, including the fallback paths, so the result type never
         depends on how many tenants happened to be pending.
         Single-tenant buckets and mode="reference" services fall back to
@@ -514,9 +534,9 @@ class SolverService:
                 for i, cols in enumerate(tenant_stacks):
                     stacked[i, :, :ks[i]] = cols
                 bs, _ = pad_rhs_pow2(jnp.asarray(stacked))  # (M, n, k_pad)
-            pp = self._packed_plan(sig, tuple(bucket))
+            pp, idx = self._packed_plan(sig, bucket)
             with tracing.span("service.execute"):
-                xs = _execute_arena_packed_donated(pp, bs)
+                xs = _execute_arena_packed_selected_donated(pp, idx, bs)
             # one device->host transfer; per-tenant scatter below is one
             # (n, k_id) copy each, so no tenant's answer pins the whole
             # fleet buffer in memory after delivery

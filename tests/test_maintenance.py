@@ -20,6 +20,7 @@ Pinned here:
 * the fleet staggers repair windows (repair token) and a maintaining
   replica is `degraded`, never quarantined.
 """
+import threading
 import time
 
 import jax
@@ -242,6 +243,51 @@ def test_hot_block_repairs_only_the_hot_array():
     assert h["repairs"] > 0
     # every repair re-programmed exactly one array: the hot one
     assert h["blocks_repaired"] == h["repairs"]
+
+
+class _PackSink:
+    """What `tracing.enable` needs of a sink, keeping `service.pack`."""
+
+    def __init__(self):
+        self.spans, self.active, self._lock = [], True, threading.Lock()
+
+    def hits(self):
+        return [s[3]["hit"] for s in self.spans if s[0] == "service.pack"]
+
+
+def test_clocked_tenants_keep_their_resident_stack():
+    """Several tenants of one signature on a moving clock: every dispatch
+    re-finalizes each tracked plan at its new age (`service.refresh`),
+    which rewrites the tenant's row of the resident packed stack, so
+    only the first packed flush builds the stack and every answer is
+    the current aged plan's."""
+    from repro.runtime import tracing
+    sink = _PackSink()
+    clock = DeviceClock()
+    ids = ("m0", "m1", "m2")
+    tracing.enable(sink)
+    try:
+        with _engine(clock, scrub=False) as eng:
+            for i, mid in enumerate(ids):
+                eng.program(mid, wishart(jax.random.fold_in(KEY, i), N),
+                            jax.random.fold_in(KEY, 10 + i))
+            for _ in range(3):
+                clock.advance(0.1)
+                bs = {mid: RNG.standard_normal(N).astype(np.float32)
+                      for mid in ids}
+                futs = {mid: eng.submit(mid, b) for mid, b in bs.items()}
+                eng.flush_now()
+                for mid, f in futs.items():
+                    np.testing.assert_allclose(
+                        np.asarray(f.result(timeout=30).x),
+                        np.asarray(eng.service.solver(mid).solve(bs[mid])),
+                        rtol=1e-5, atol=1e-6)
+            refreshes = eng.stats.age_refreshes
+    finally:
+        tracing.disable()
+    assert refreshes >= 3 * len(ids)
+    hits = sink.hits()
+    assert len(hits) >= 3 and hits[0] == 0 and all(hits[1:])
 
 
 def test_accelerated_drift_event_fires_once():

@@ -338,14 +338,14 @@ def test_flush_all_reference_mode_falls_back():
 
 
 def test_reprogram_invalidates_pack_cache():
-    """Re-programming a tenant drops every cached pack containing it, so
-    the next flush_all packs the new plan (and solves the new matrix).
-    The cache holds one (id tuple, pack) per signature."""
+    """Re-programming a tenant drops the resident stack holding it, so
+    the next flush_all stacks the new plan (and solves the new matrix).
+    The cache holds one resident (rows, pack) per signature."""
     svc, ids = _service(m=2)
     for mid in ids:
         svc.submit(mid, jax.random.normal(KB, (N,)))
     svc.flush_all()
-    assert [ids_ for ids_, _ in svc._packs.values()] == [tuple(ids)]
+    assert [tuple(rows) for rows, _ in svc._packs.values()] == [tuple(ids)]
     a_new = wishart(jax.random.fold_in(KA, 77), N)
     svc.program(ids[0], a_new, jax.random.fold_in(KN, 77))
     assert not svc._packs
@@ -356,6 +356,253 @@ def test_reprogram_invalidates_pack_cache():
     np.testing.assert_allclose(np.asarray(out[ids[0]][:, 0]),
                                np.asarray(svc.solver(ids[0]).solve(b)),
                                rtol=1e-5, atol=1e-6)
+
+
+def _assert_flush_answers_new_plan(svc, ids):
+    """Both tenants pending: the stack is rebuilt and answers with the
+    current solvers' numbers."""
+    b = jax.random.normal(jax.random.fold_in(KB, 5), (N,))
+    for mid in ids:
+        svc.submit(mid, b)
+    out = svc.flush_all()
+    for mid in ids:
+        np.testing.assert_allclose(np.asarray(out[mid][:, 0]),
+                                   np.asarray(svc.solver(mid).solve(b)),
+                                   rtol=1e-5, atol=1e-6)
+
+
+def test_refresh_rewrites_resident_row(sink):
+    """`refresh` of a member (a maintained variant of its solver) writes
+    that member's row of the resident stack in place: the next flush
+    reuses the stack (`service.pack` hit 1) and answers from the new
+    plan, and the other members' rows are untouched."""
+    svc, ids = _service(m=3)
+    assert _flush_pairs(svc, [ids[:2]], sink) == [0]
+    before = np.asarray(next(iter(svc._packs.values()))[1].stacks[0])
+    fresh = blockamc.ProgrammedSolver.program(
+        svc.dense(ids[0]), jax.random.fold_in(KN, 78), CFG, 2)
+    svc.refresh(ids[0], fresh)
+    rows, pp = next(iter(svc._packs.values()))
+    assert pp.num_instances == 3 and rows[ids[0]] == 0
+    after = np.asarray(pp.stacks[0])
+    np.testing.assert_array_equal(after[0], np.asarray(fresh.arena.stacks[0]))
+    np.testing.assert_array_equal(after[1:], before[1:])
+    assert not np.array_equal(after[0], before[0])
+    np.testing.assert_array_equal(np.asarray(pp.program_ops[0]),
+                                  np.asarray(fresh.arena.program[0]))
+    np.testing.assert_array_equal(np.asarray(pp.scale[0]),
+                                  np.asarray(fresh.arena.scale))
+    _assert_flush_answers_new_plan(svc, ids[:2])
+    hits = [s[3]["hit"] for s in sink.spans if s[0] == "service.pack"]
+    assert hits == [0, 1]
+
+
+def test_refresh_of_non_member_leaves_stack():
+    """A refresh of a tenant the resident stack does not hold (there is
+    no stack yet, or the tenant joined after it was built) swaps only
+    its solver handle."""
+    svc, ids = _service(m=2)
+    fresh = blockamc.ProgrammedSolver.program(
+        svc.dense(ids[1]), jax.random.fold_in(KN, 80), CFG, 2)
+    svc.refresh(ids[1], fresh)                   # no stack yet
+    assert not svc._packs and svc.solver(ids[1]) is fresh
+    for mid in ids:
+        svc.submit(mid, jax.random.normal(KB, (N,)))
+    svc.flush_all()
+    (entry,) = svc._packs.values()
+    a2 = wishart(jax.random.fold_in(KA, 2), N)
+    svc.program("m2", a2, jax.random.fold_in(KN, 2))   # joins, not a member
+    fresh2 = blockamc.ProgrammedSolver.program(
+        a2, jax.random.fold_in(KN, 81), CFG, 2)
+    svc.refresh("m2", fresh2)
+    assert next(iter(svc._packs.values())) is entry
+    assert svc.solver("m2") is fresh2
+
+
+def test_install_invalidates_pack_cache():
+    """`install` over a member (checkpoint restore of another plan)
+    drops the resident stack, and the next flush answers from it."""
+    svc, ids = _service(m=2)
+    for mid in ids:
+        svc.submit(mid, jax.random.normal(KB, (N,)))
+    svc.flush_all()
+    assert svc._packs
+    a_new = wishart(jax.random.fold_in(KA, 79), N)
+    restored = blockamc.ProgrammedSolver.program(
+        a_new, jax.random.fold_in(KN, 79), CFG, 2)
+    svc.install(ids[0], restored, a_new)
+    assert not svc._packs
+    _assert_flush_answers_new_plan(svc, ids)
+
+
+@pytest.fixture(scope="module")
+def service4():
+    """Four same-signature tenants, shared by the subset tests (each
+    flushes everything it submits)."""
+    return _service(m=4)
+
+
+def _subset_cols(subset):
+    """Distinct, ragged (1, 2, 3, ...) rhs columns for each tenant."""
+    return {mid: [np.asarray(jax.random.normal(
+        jax.random.fold_in(KB, 10 * int(mid[1:]) + c), (N,)))
+        for c in range(j + 1)] for j, mid in enumerate(subset)}
+
+
+def _padded(cols, k_pad):
+    out = np.zeros((N, k_pad), np.float32)
+    out[:, :len(cols)] = np.stack(cols, axis=1)
+    return out
+
+
+@pytest.mark.parametrize("order", ["given", "reversed"])
+@pytest.mark.parametrize("subset", [("m2",), ("m0", "m3"),
+                                    ("m3", "m1", "m2"),
+                                    ("m0", "m1", "m2", "m3")])
+def test_flush_all_subset_matches_per_tenant(service4, subset, order):
+    """Any pending subset, in any bucket order, selected from the
+    resident stack: each tenant's answer is bit-for-bit its own jitted
+    `execute_arena` on its padded columns, and the per-subset
+    `pack_arena_plans` + `execute_arena_packed` of the same bucket (jnp
+    path on the CPU; a one-tenant bucket takes `solve_many`)."""
+    svc, _ = service4
+    bucket = list(subset if order == "given" else reversed(subset))
+    cols = _subset_cols(subset)
+    for c in range(max(map(len, cols.values()))):   # interleaved submits
+        for mid in reversed(bucket):
+            if c < len(cols[mid]):
+                svc.submit(mid, cols[mid][c])
+    out = svc.flush_all(matrix_ids=bucket)
+    assert list(out) == bucket
+    k_pad = 1 << (max(map(len, cols.values())) - 1).bit_length()
+    bs = np.stack([_padded(cols[mid], k_pad) for mid in bucket])
+    old = np.asarray(blockamc._execute_arena_packed(
+        blockamc.pack_arena_plans([svc.solver(mid).arena
+                                   for mid in bucket]), jnp.asarray(bs)))
+    for i, mid in enumerate(bucket):
+        k = len(cols[mid])
+        own = np.asarray(blockamc._execute_arena(
+            svc.solver(mid).arena, jnp.asarray(bs[i]), use_kernel=False))
+        np.testing.assert_array_equal(out[mid], own[:, :k])
+        np.testing.assert_array_equal(out[mid], old[i, :, :k])
+
+
+@pytest.mark.parametrize("subset", [("m0", "m3"), ("m3", "m1", "m2")])
+def test_selected_kernel_matches_per_tenant(service4, subset):
+    """The index-selected executor on the Pallas path (interpret mode
+    off the chip) answers each tenant like its own `execute_arena`,
+    within the packed-vs-loop tolerance."""
+    svc, _ = service4
+    for mid in subset:                  # build the resident stack
+        svc.submit(mid, jnp.zeros((N,)))
+    svc.flush_all()
+    rows, pp = svc._packs[svc.signature(subset[0])]
+    assert pp.program_ops is not None and pp.num_instances == 4
+    idx = jnp.asarray([rows[mid] for mid in subset], jnp.int32)
+    bs = jax.random.normal(KB, (len(subset), N, 2))
+    xs = blockamc.execute_arena_packed_selected(pp, idx, bs,
+                                                use_kernel=True)
+    for i, mid in enumerate(subset):
+        np.testing.assert_allclose(
+            np.asarray(xs[i]),
+            np.asarray(blockamc.execute_arena(svc.solver(mid).arena, bs[i],
+                                              use_kernel=False)),
+            rtol=1e-5, atol=1e-6)
+
+
+def _flush_pairs(svc, pairs, sink):
+    """Flush each pair with one rhs per tenant; the `service.pack` hits."""
+    for pair in pairs:
+        for mid in pair:
+            svc.submit(mid, jax.random.normal(KB, (N,)))
+        svc.flush_all()
+    return [s[3]["hit"] for s in sink.spans if s[0] == "service.pack"]
+
+
+class _Sink:
+    """The span sink `tracing.enable` needs (the benchmark's Recorder)."""
+
+    def __init__(self):
+        import threading
+        self.spans, self.active, self._lock = [], True, threading.Lock()
+
+
+@pytest.fixture
+def sink():
+    from repro.runtime import tracing
+    s = _Sink()
+    tracing.enable(s)
+    try:
+        yield s
+    finally:
+        tracing.disable()
+
+
+def test_resident_stack_reused_across_subsets(sink):
+    """After the first packed flush builds the signature's resident
+    stack, flushes of other subsets reuse it: `service.pack` hit 1 and
+    the same stack object."""
+    svc, ids = _service(m=4)
+    hits = _flush_pairs(svc, [ids[:2]], sink)
+    assert hits == [0]
+    (entry,) = svc._packs.values()
+    assert tuple(entry[0]) == tuple(ids)
+    hits = _flush_pairs(svc, [ids[2:], (ids[1], ids[3]), ids], sink)
+    assert hits == [0, 1, 1, 1]
+    assert next(iter(svc._packs.values())) is entry
+
+
+def test_equal_shapes_do_not_recompile():
+    """Flushes of different subsets at the same (M, k) reuse the first
+    one's executor: its jit cache and the backend compile count stay
+    put after the first flush."""
+    svc, ids = _service(m=4)
+    compiles = []
+
+    def on_duration(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def flush(pair):
+        for mid in pair:
+            svc.submit(mid, jax.random.normal(KB, (N,)))
+        svc.flush_all()
+
+    executor = blockamc._execute_arena_packed_selected_donated
+    flush((ids[0], ids[1]))
+    cached = executor._cache_size()
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        flush((ids[2], ids[3]))
+        flush((ids[1], ids[3]))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert executor._cache_size() == cached
+    assert compiles == []
+
+
+def test_new_tenant_rebuilds_resident_stack(sink):
+    """A tenant programmed into the signature after its stack was built
+    rebuilds it, at the new member count, on its first pending flush,
+    and answers with its own numbers."""
+    svc, ids = _service(m=3)
+    assert _flush_pairs(svc, [ids[:2]], sink) == [0]
+    (entry,) = svc._packs.values()
+    assert entry[1].num_instances == 3 and len(entry[0]) == 3
+    svc.program("m3", wishart(jax.random.fold_in(KA, 3), N),
+                jax.random.fold_in(KN, 3))
+    assert svc._packs                    # not a member: nothing dropped
+    b = jax.random.normal(jax.random.fold_in(KB, 3), (N,))
+    svc.submit("m0", b)
+    svc.submit("m3", b)
+    out = svc.flush_all()
+    np.testing.assert_allclose(np.asarray(out["m3"][:, 0]),
+                               np.asarray(svc.solver("m3").solve(b)),
+                               rtol=1e-5, atol=1e-6)
+    assert _flush_pairs(svc, [(ids[1], ids[2])], sink) == [0, 0, 1]
+    (entry,) = svc._packs.values()
+    assert tuple(entry[0]) == (*ids, "m3")
+    assert entry[1].num_instances == 4
 
 
 def test_scheduler_continuous_batching_flush():
@@ -426,19 +673,20 @@ def test_scheduler_survives_injected_dispatch_failure(monkeypatch):
     sched.check_consistency()
 
     # inject: the packed executor dies on its next invocation only
-    real = blockamc._execute_arena_packed_donated
+    real = blockamc._execute_arena_packed_selected_donated
     blows = {"left": 1}
 
-    def exploding(pp, bs):
+    def exploding(pp, idx, bs):
         if blows["left"]:
             blows["left"] -= 1
             raise RuntimeError("injected device OOM")
-        return real(pp, bs)
+        return real(pp, idx, bs)
 
-    monkeypatch.setattr(blockamc, "_execute_arena_packed_donated",
+    monkeypatch.setattr(blockamc, "_execute_arena_packed_selected_donated",
                         exploding)
     import repro.serve.solver_service as ss
-    monkeypatch.setattr(ss, "_execute_arena_packed_donated", exploding)
+    monkeypatch.setattr(ss, "_execute_arena_packed_selected_donated",
+                        exploding)
 
     with pytest.raises(RuntimeError, match="injected device OOM"):
         sched.drain()
@@ -469,19 +717,20 @@ def test_scheduler_failure_on_triggering_submit_keeps_ticket(monkeypatch):
     b1 = jax.random.normal(jax.random.fold_in(KB, 1), (N,))
     t0 = sched.submit(ids[0], b0)
 
-    real = blockamc._execute_arena_packed_donated
+    real = blockamc._execute_arena_packed_selected_donated
     blows = {"left": 1}
 
-    def exploding(pp, bs):
+    def exploding(pp, idx, bs):
         if blows["left"]:
             blows["left"] -= 1
             raise RuntimeError("injected")
-        return real(pp, bs)
+        return real(pp, idx, bs)
 
-    monkeypatch.setattr(blockamc, "_execute_arena_packed_donated",
+    monkeypatch.setattr(blockamc, "_execute_arena_packed_selected_donated",
                         exploding)
     import repro.serve.solver_service as ss
-    monkeypatch.setattr(ss, "_execute_arena_packed_donated", exploding)
+    monkeypatch.setattr(ss, "_execute_arena_packed_selected_donated",
+                        exploding)
 
     with pytest.raises(RuntimeError, match="injected"):
         sched.submit(ids[1], b1)                 # 2nd pending -> flush dies

@@ -72,6 +72,27 @@ def test_arena_packed_apply_compiles(one_chip, plan, m):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_selected_packed_executor_compiles(one_chip, monkeypatch):
+    """The served flush's executor at the paper's fleet: 5 tenants
+    gathered by index from a 16-tenant resident stack and run through
+    the packed megakernel, as one program."""
+    n, array_size, stages = PLANS["paper_256"]
+    cfg = AnalogConfig(array_size=array_size, nonideal=PAPER_FULL)
+    pp = jax.eval_shape(partial(blockamc.program_packed, cfg=cfg,
+                                stages=stages),
+                        jax.ShapeDtypeStruct((16, n, n), jnp.float32),
+                        jax.ShapeDtypeStruct((16, 2), jnp.uint32))
+    pp = jax.tree_util.tree_map(lambda x: _sds(x, one_chip), pp)
+    idx = jax.ShapeDtypeStruct((5,), jnp.int32, sharding=one_chip)
+    bs = jax.ShapeDtypeStruct((5, n, 8), jnp.float32, sharding=one_chip)
+    # the executor picks the kernel from the default backend (the CPU
+    # here); a fresh jit keeps this trace out of the served one's cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled = jax.jit(blockamc.execute_arena_packed_selected,
+                       donate_argnums=(2,)).lower(pp, idx, bs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 # (batch, block rows, block size, rhs width) before ops.py pads s and k to
 # 128; (2, 64, 64, 64) is the nodal oracle's effective-conductance solve
 # of a 64^2 paper array, past the default scoped VMEM limit.

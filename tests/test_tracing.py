@@ -200,11 +200,21 @@ def test_pack_hit_reads_the_membership(sink):
         svc.flush_all()
         return sink.named("service.pack")[-1][3]
 
+    # hit: the signature's resident stack was reused without a rebuild,
+    # whichever of its tenants are pending
     assert flush(["a", "b"])["hit"] == 0
     assert flush(["a", "b"])["hit"] == 1
     attrs = flush(["a", "c"])
-    assert attrs["hit"] == 0 and attrs["tenants"] == 2
-    assert flush(["a", "c"])["hit"] == 1
+    assert attrs["hit"] == 1 and attrs["tenants"] == 2
+    svc.program("d", wishart(jax.random.fold_in(KEY, 9), N),
+                jax.random.fold_in(KEY, 9))
+    assert flush(["a", "d"])["hit"] == 0      # a new member rebuilds it
+    assert flush(["b", "c", "d"])["hit"] == 1
+    svc.refresh("b", svc.solver("c"))
+    assert flush(["b", "c"])["hit"] == 1      # refresh rewrites its row
+    svc.program("b", wishart(jax.random.fold_in(KEY, 10), N),
+                jax.random.fold_in(KEY, 10))
+    assert flush(["b", "c"])["hit"] == 0      # a re-programmed member drops it
 
 
 def test_solve_batched_spans(sink):
