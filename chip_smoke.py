@@ -20,7 +20,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import math
 import os
 import sys
 import time
@@ -135,14 +134,13 @@ def serve_phase(*, n, stages, array_size, tenants, rhs, replicas=1,
     mats, keys, bs = make_tenants(n, tenants, rhs, seed)
     total = tenants * rhs
     devices = jax.devices()[:replicas]
-    # No signature affinity: the least-loaded pick deals the requests of
-    # the one plan signature round-robin over the replicas, so every
-    # replica serves.  No bucket fills or ages out while they are queued;
-    # flush_now then answers each replica's share in one packed dispatch.
+    # The router's defaults: the one plan signature stays on a replica
+    # until it has been routed a full batch, then moves to the next, so
+    # with a batch of total / replicas each replica's share fills one
+    # size-triggered packed dispatch.  Nothing ages out while queued.
     fleet = ReplicatedSolverFleet(
         lambda: SolverService(cfg, stages), replicas, devices=devices,
-        affinity_slack=-math.inf,
-        engine_kw=dict(max_batch=total, max_pending=total,
+        engine_kw=dict(max_batch=-(-total // replicas), max_pending=total,
                        flush_interval=timeout_s))
     ids = [f"t{i}" for i in range(tenants)]
     t0 = time.perf_counter()

@@ -2057,6 +2057,17 @@ class ProgrammedSolver:
         return ProgrammedSolver(fin, arena, self._mode, fplan=fplan,
                                 parts=self._parts, stages=self._stages)
 
+    def placed(self, device) -> "ProgrammedSolver":
+        """This solver with every array copied to `device`.
+
+        Nothing is re-programmed, so the copy serves bit-identical
+        arrays; the flat plan and partitioned system come along, so the
+        copy can still age and be repaired in place."""
+        fin, arena, fplan, parts = jax.device_put(
+            (self._fin, self._arena, self._fplan, self._parts), device)
+        return ProgrammedSolver(fin, arena, self._mode, fplan=fplan,
+                                parts=parts, stages=self._stages)
+
     @property
     def arena(self) -> ArenaPlan:
         if self._arena is None:

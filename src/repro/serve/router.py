@@ -6,22 +6,28 @@ replicas, each with its own `SolverService`, worker thread and (when the
 host has them) its own device via `ElasticMesh.assign_replicas`, behind a
 router that owns admission, placement, hedging and failure recovery.
 
-**Replicated programming.** `program` programs every matrix on every
-replica with the *same* key.  Programming is deterministic in (matrix,
-key, cfg), so the conductance stacks are bit-identical across replicas -
-which is what makes three things free: any replica can answer any
-request, any survivor is a valid pytree template for checkpoint restore
-(stackability invariant), and replayed requests get the same answers the
-dead replica would have produced.
+**Replicated programming.** `program` programs each matrix once, on the
+lead replica, and installs a copy of the programmed solver on every other
+replica, placed on that replica's own device and checked by its canary
+against the lead's calibrated trip (a rejected copy falls back to
+programming that replica under the same key).  The conductance stacks
+are therefore bit-identical across replicas - which is what makes three
+things free: any replica can answer any request, any survivor is a valid
+pytree template for checkpoint restore (stackability invariant), and
+replayed requests get the same answers the dead replica would have
+produced.
 
 **Health-scored routing.** Each replica carries an EWMA composite score:
 canary-residual ratio (current residual / calibrated trip - the physics
 signal), deadline-miss rate (the SLO signal), and queue depth (the load
 signal).  Lower is healthier.  Placement is least-loaded with
-signature-affinity: same-signature requests prefer the replica already
-accumulating that signature's batch (packed dispatch efficiency), unless
-its score has fallen behind the best replica by more than
-`affinity_slack`.
+signature-affinity: same-signature requests go to the replica already
+accumulating that signature's batch (packed dispatch efficiency) until
+it has been routed a full batch of it (its engine's `max_batch`), then
+the signature moves to the next-best replica; it moves sooner if the
+affine replica's score falls behind the best by more than
+`affinity_slack`.  So one hot signature spreads over the fleet in full
+batches instead of piling onto one replica.
 
 **Hedged requests.** A deadline-critical submit (`hedge=True`, or any
 deadlined submit when `hedge_delay` is set) arms a timer: if the primary
@@ -108,6 +114,9 @@ class FleetStats:
     repairs: int = 0           # block-repair rounds across the fleet
     recheckpoints: int = 0     # repaired plans persisted to the store
     maintenance_windows: int = 0   # repair-token grants (staggered)
+    affinity_moves: int = 0    # picks that moved a signature's affinity
+    # legs launched, per replica name
+    routed: Dict[str, int] = dataclasses.field(default_factory=dict)
     restore_s: List[float] = dataclasses.field(default_factory=list)
     reprogram_s: List[float] = dataclasses.field(default_factory=list)
 
@@ -241,6 +250,7 @@ class ReplicatedSolverFleet:
             for i in range(n_replicas)]
         self._matrices: Dict[str, _MatrixRecord] = {}
         self._affinity: Dict[tuple, str] = {}   # sig -> replica name
+        self._run: Dict[tuple, int] = {}        # sig -> picks since moved
         self._submits = 0                       # chaos corruption counter
         self._running = False
         self._monitor: Optional[threading.Thread] = None
@@ -306,30 +316,50 @@ class ReplicatedSolverFleet:
     # ------------------------------------------------------------------
 
     def program(self, matrix_id: str, a, key=None, cfg=None) -> None:
-        """Program `a` on EVERY replica under the same key, then persist.
+        """Program `a` on the lead replica, copy it to every other one,
+        then persist.
 
-        Same key => bit-identical programmed stacks on every replica (the
-        replicated-programming invariant above).  With a store attached,
-        replica r0's solver is checkpointed together with the calibrated
-        canary trip, so a future replacement can restore instead of
-        re-program."""
+        The lead (the first live replica) pays the programming; each
+        other replica installs the lead's solver copied to its own
+        device, against the lead's calibrated canary trip, so every
+        replica serves bit-identical stacks (the replicated-programming
+        invariant above).  A replica whose canary rejects the copy is
+        programmed itself under the same key.  With a store attached,
+        the lead's solver is checkpointed together with the trip, so a
+        future replacement can restore instead of re-program."""
         key = key if key is not None else jax.random.PRNGKey(0)
         a_host = np.asarray(a)
         with self._lock:
             replicas = [r for r in self._replicas if r.state != "dead"]
         if not replicas:
             raise NoReplicaAvailableError("no live replica to program")
-        for r in replicas:
-            r.engine.program(matrix_id, a, key, cfg=cfg)
         lead = replicas[0]
-        sig = lead.engine.service.signature(matrix_id)
-        trip = lead.engine.matrix_trip(matrix_id)
-        with self._lock:
-            self._matrices[matrix_id] = _MatrixRecord(
-                a_host, key, cfg, sig, trip)
-        if self.store is not None:
-            self.store.save(matrix_id, lead.engine.service.solver(matrix_id),
-                            a_host, key, sig, extra={"trip": float(trip)})
+        sp = tracing.span("fleet.program")
+        if sp:
+            sp.attrs.update(tenant=matrix_id, replicas=len(replicas))
+        with sp:
+            lead.engine.program(matrix_id, a, key, cfg=cfg)
+            solver = lead.engine.service.solver(matrix_id)
+            trip = lead.engine.matrix_trip(matrix_id)
+            copies = 0
+            for r in replicas[1:]:
+                try:
+                    r.engine.install(matrix_id, solver.placed(r.device), a,
+                                     key, trip, cfg=cfg)
+                    copies += 1
+                except CheckpointRejectedError as e:
+                    log.warning("copy of %r rejected on replica %r (%s); "
+                                "programming it there", matrix_id, r.name, e)
+                    r.engine.program(matrix_id, a, key, cfg=cfg)
+            if sp:
+                sp.attrs["copies"] = copies
+            sig = lead.engine.service.signature(matrix_id)
+            with self._lock:
+                self._matrices[matrix_id] = _MatrixRecord(
+                    a_host, key, cfg, sig, trip)
+            if self.store is not None:
+                self.store.save(matrix_id, solver, a_host, key, sig,
+                                extra={"trip": float(trip)})
 
     # ------------------------------------------------------------------
     # routing
@@ -339,7 +369,13 @@ class ReplicatedSolverFleet:
               exclude: Tuple[str, ...] = ()) -> _Replica:
         """Least-loaded routable replica, with signature affinity: the
         replica already accumulating this signature keeps it while its
-        score stays within `affinity_slack` of the best candidate.
+        score stays within `affinity_slack` of the best candidate and
+        until it has been routed a full batch of it (its engine's
+        `max_batch`) since it took the signature; then the signature
+        moves to the best other candidate (the next in fleet order
+        among equals), so each replica's bucket fills to a
+        size-triggered packed dispatch and the next batch starts on
+        another replica.
 
         Ranking quantizes the health score (quarter-point buckets) before
         load and assignment count: sub-noise EWMA differences - e.g. the
@@ -359,10 +395,11 @@ class ReplicatedSolverFleet:
         assigned: Dict[str, int] = {}
         for name in self._affinity.values():
             assigned[name] = assigned.get(name, 0) + 1
-        cands.sort(key=lambda r: (0 if r.state == "active" else 1,
-                                  int(r.score.value() / 0.25),
-                                  len(r.inflight),
-                                  assigned.get(r.name, 0)))
+
+        def rank(r):
+            return (0 if r.state == "active" else 1,
+                    int(r.score.value() / 0.25), len(r.inflight))
+        cands.sort(key=lambda r: rank(r) + (assigned.get(r.name, 0),))
         best = cands[0]
         aff = self._affinity.get(sig)
         if aff is not None and aff != best.name:
@@ -372,7 +409,20 @@ class ReplicatedSolverFleet:
                             <= self.affinity_slack):
                         best = r
                     break
-        self._affinity[sig] = best.name
+        if best.name == aff and len(cands) > 1 and \
+                self._run.get(sig, 0) >= best.engine.max_batch:
+            # a full batch routed: the best other replica takes the
+            # signature, the next one in fleet order among equals
+            at, n = self._replicas.index(best), len(self._replicas)
+            best = min((r for r in cands if r is not best),
+                       key=lambda r: rank(r) + (
+                           (self._replicas.index(r) - at) % n,))
+        if best.name != aff:
+            if aff is not None:
+                self.stats.affinity_moves += 1
+            self._affinity[sig] = best.name
+            self._run[sig] = 0
+        self._run[sig] += 1
         return best
 
     def submit(self, matrix_id: str, b, *,
@@ -397,7 +447,11 @@ class ReplicatedSolverFleet:
             # NoReplicaAvailableError before any counter moves, so a
             # failed admission leaves `stats`/`_submits` (and the chaos
             # corruption schedule keyed on `_submits`) untouched
+            moves = self.stats.affinity_moves
             replica = self._pick(rec.sig)
+            if sp:
+                sp.attrs.update(replica=replica.name,
+                                moved=self.stats.affinity_moves - moves)
             self._submits += 1
             now = time.monotonic()
             deadline = (None if deadline_s is None
@@ -436,6 +490,8 @@ class ReplicatedSolverFleet:
             return
         req.legs.append(inner)
         req.replicas_tried.append(replica.name)
+        routed = self.stats.routed
+        routed[replica.name] = routed.get(replica.name, 0) + 1
         replica.inflight[inner] = req
         if replay:
             self.stats.replays += 1

@@ -104,6 +104,72 @@ def test_fleet_serves_and_spreads(tmp_path):
     assert fleet.stats.deaths == 0 and fleet.stats.replays == 0
 
 
+def test_one_replica_pick_keeps_the_signature():
+    """With one replica every pick is that replica: the signature's
+    affinity never moves, however many full batches it routes."""
+    fleet = ReplicatedSolverFleet(_service(), 1, engine_kw=ENGINE_KW)
+    a = _matrix(1)
+    with fleet:
+        fleet.program("m", a, KEY)
+        sig = plan_signature(N, 1, CFG)
+        picks = {fleet._pick(sig).name for _ in range(5 * 4)}
+        assert picks == {"r0"} and fleet._affinity[sig] == "r0"
+        futs = [fleet.submit("m", np.full(N, 1.0 + j, np.float32))
+                for j in range(12)]
+        fleet.flush_now()
+        for fut in futs:
+            assert np.all(np.isfinite(fut.result(timeout=10).x))
+    assert fleet._affinity == {sig: "r0"}
+    assert fleet.stats.affinity_moves == 0
+    assert fleet.stats.routed == {"r0": 12}
+
+
+def test_concurrent_submits_count_every_leg():
+    """More submitting threads than cores, a short switch interval: every
+    leg routed is counted once, on the replica that answered it.  (The
+    burst queues ~48 requests a replica, whose queue term alone would
+    pass the default drain score: this test counts, it does not drain.
+    Each replica admits the whole burst, so no leg is refused however
+    unevenly a loaded host lets the threads run.)"""
+    import sys
+    import threading
+    threads, each = 16, 12
+    fleet = ReplicatedSolverFleet(
+        _service(), 4, engine_kw=dict(ENGINE_KW, max_pending=threads * each),
+        drain_score=10.0)
+    futs, lock = [], threading.Lock()
+
+    def client(t):
+        for j in range(each):
+            fut = fleet.submit("m", np.full(N, 1.0 + t + j, np.float32))
+            with lock:
+                futs.append(fut)
+    with fleet:
+        fleet.program("m", _matrix(2), KEY)
+        pool = [threading.Thread(target=client, args=(t,))
+                for t in range(threads)]
+        # the short switch interval only while the clients submit: the
+        # answers are then awaited at the normal pace
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            for th in pool:
+                th.start()
+            for th in pool:
+                th.join(timeout=120)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(th.is_alive() for th in pool)
+        fleet.flush_now()
+        for fut in futs:
+            assert np.all(np.isfinite(fut.result(timeout=120).x))
+        answered = {name: e.stats.answered
+                    for name, e in fleet.replica_engines().items()}
+    assert len(futs) == threads * each == fleet.stats.submitted
+    assert fleet.stats.routed == answered
+    assert sum(answered.values()) == threads * each
+
+
 def test_fleet_submit_validation():
     fleet = ReplicatedSolverFleet(_service(), 1, engine_kw=ENGINE_KW)
     with pytest.raises(RuntimeError):      # not running yet
@@ -160,22 +226,26 @@ def test_replica_death_replay_and_checkpoint_restore(tmp_path):
         for i, (mid, a) in enumerate(mats.items()):
             fleet.program(mid, a, jax.random.fold_in(KEY, 200 + i))
 
+        # Each request is answered before the next is sent, so each is a
+        # dispatch of its own, however slow the machine.  The tenants
+        # share one signature, whose first `max_batch` (4) picks go to r0,
+        # the replica that takes it: the death scripted on r0's dispatch 1
+        # fires by construction, with that request in flight.
         futs = []
         for wave in range(4):
             for mid in mats:
                 for j in range(3):
                     b = np.asarray(jax.random.normal(
                         jax.random.fold_in(KEY, 17 * wave + j), (N,)))
-                    futs.append((mid, b,
-                                 fleet.submit(mid, b, deadline_s=5.0)))
-            fleet.flush_now()
-            time.sleep(0.05)
-
-        for mid, b, fut in futs:
-            res = fut.result(timeout=15)   # NEVER hangs
-            assert np.all(np.isfinite(np.asarray(res.x)))
-            assert _resid(mats[mid], np.asarray(res.x), b) < ANALOG_RES
-            assert not res.deadline_missed  # healthy tenants: zero misses
+                    fut = fleet.submit(mid, b, deadline_s=5.0)
+                    futs.append((mid, b, fut))
+                    fleet.flush_now()
+                    res = fut.result(timeout=15)   # NEVER hangs
+                    assert np.all(np.isfinite(np.asarray(res.x)))
+                    assert _resid(mats[mid], np.asarray(res.x),
+                                  b) < ANALOG_RES
+                    # healthy tenants: zero misses
+                    assert not res.deadline_missed
         assert _wait(lambda: fleet.stats.replacements >= 1)
         # post-recovery the fleet is whole and still serves
         assert set(fleet.replica_states().values()) == {"active"}
@@ -203,27 +273,30 @@ def _run_death_recovery(store, chaos):
 
     A generator: yields the running fleet once "m" is programmed and its
     checkpoint saved (so the caller can damage the store), then drives
-    waves of traffic through the death and recovery, asserts the
-    universal invariants (every future resolves with an accurate answer;
-    the recovered fleet still serves), and yields the stopped fleet for
-    stats assertions."""
+    12 requests through the death and recovery, asserts the universal
+    invariants (every future resolves with an accurate answer; the
+    recovered fleet still serves), and yields the stopped fleet for
+    stats assertions.
+
+    Each request is answered before the next is sent, so each is a
+    dispatch of its own, however slow the machine.  The signature's
+    first `max_batch` (4) picks go to r0, the replica that takes it, so
+    a death scripted on r0's dispatch 0-3 fires by construction; the
+    requests after it go to r1, then to the replacement."""
     a = _matrix(20)
     fleet = ReplicatedSolverFleet(_service(), 2, engine_kw=ENGINE_KW,
                                   store=store, chaos=chaos)
     with fleet:
         fleet.program("m", a, jax.random.fold_in(KEY, 21))
         yield fleet                        # caller damages the store here
-        futs = []
         for wave in range(4):
             for j in range(3):
                 b = np.asarray(jax.random.normal(
                     jax.random.fold_in(KEY, 31 * wave + j), (N,)))
-                futs.append((b, fleet.submit("m", b)))
-            fleet.flush_now()
-            time.sleep(0.05)
-        for b, fut in futs:
-            res = fut.result(timeout=15)
-            assert _resid(a, np.asarray(res.x), b) < ANALOG_RES
+                fut = fleet.submit("m", b)
+                fleet.flush_now()
+                res = fut.result(timeout=15)
+                assert _resid(a, np.asarray(res.x), b) < ANALOG_RES
         assert _wait(lambda: fleet.stats.replacements >= 1)
         # the recovered fleet still serves correct answers
         b = np.asarray(jax.random.normal(jax.random.fold_in(KEY, 6), (N,)))
