@@ -787,9 +787,13 @@ class AsyncSolverEngine:
 
     def _dispatch_packed(self, groups: Dict[str, List[_Request]]):
         ids = list(groups)
-        for mid, rs in groups.items():
-            for r in rs:
-                self.service.submit(mid, r.b)
+        sp = tracing.span("engine.handoff")
+        if sp:
+            sp.attrs["rhs"] = sum(len(rs) for rs in groups.values())
+        with sp:
+            for mid, rs in groups.items():
+                for r in rs:
+                    self.service.submit(mid, r.b)
         attempts = [0]
 
         def attempt():

@@ -50,10 +50,17 @@ from repro.runtime import tracing
 def _require_float_dtype(name: str, arr) -> None:
     """Front-door dtype gate: analog programming and dispatch are float
     pipelines; an int/bool/complex input would be silently cast (or crash
-    deep inside a packed dispatch), so reject it with the field name."""
-    if not jnp.issubdtype(jnp.asarray(arr).dtype, jnp.floating):
+    deep inside a packed dispatch), so reject it with the field name.
+
+    Reads the dtype the input carries, so a host rhs is never uploaded to
+    the device just to be checked; only Python scalars and lists, which
+    carry none, go through `np.asarray`."""
+    dtype = getattr(arr, "dtype", None)
+    if dtype is None:
+        dtype = np.asarray(arr).dtype
+    if not jnp.issubdtype(dtype, jnp.floating):
         raise ValueError(
-            f"{name} must have a floating dtype, got {jnp.asarray(arr).dtype}"
+            f"{name} must have a floating dtype, got {dtype}"
             f" - cast explicitly if the int/bool input is intentional")
 
 

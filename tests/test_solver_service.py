@@ -179,6 +179,40 @@ def test_submit_rejects_nonfinite_and_wrong_dtype():
     assert svc.pending("m0") == 0               # nothing was queued
 
 
+# (where, input, accepted).  Every case runs under the strictest host-to-
+# device guard: "disallow_explicit" also refuses `jnp.asarray`/`device_put`
+# of a host array, which plain "disallow" lets through, so the gate must
+# reach its verdict from the dtype the input carries, with no upload.
+GATE_CASES = {
+    "f32": ("submit", lambda: np.ones(N, np.float32), True),
+    "f64": ("submit", lambda: np.ones(N, np.float64), True),
+    "bf16_jax": ("submit", lambda: jnp.ones(N, jnp.bfloat16), True),
+    "int64": ("submit", lambda: np.arange(N), False),
+    "bool": ("submit", lambda: np.ones(N, bool), False),
+    "complex": ("submit", lambda: np.ones(N, np.complex64), False),
+    "int32_jax_matrix": ("program",
+                         lambda: jnp.eye(N, dtype=jnp.int32), False),
+}
+
+
+@pytest.mark.parametrize("case", list(GATE_CASES))
+def test_dtype_gate_reads_the_dtype_on_the_host(case):
+    where, make, accepted = GATE_CASES[case]
+    svc, _ = _service()
+    x = make()                                  # made before the guard
+    with jax.transfer_guard_host_to_device("disallow_explicit"):
+        if accepted:
+            assert svc.submit("m0", x) == 0
+        elif where == "submit":
+            with pytest.raises(ValueError, match="floating"):
+                svc.submit("m0", x)
+        else:
+            with pytest.raises(ValueError, match="floating"):
+                svc.program("m1", x, KN)
+    assert svc.pending("m0") == (1 if accepted else 0)
+    assert "m1" not in svc.matrix_ids           # nothing after a rejection
+
+
 def test_nan_rhs_cannot_corrupt_cobatched_tenants():
     """One tenant's NaN rhs must be rejected at its own front door - a
     co-batched healthy tenant's packed answers stay exactly what they

@@ -178,6 +178,33 @@ def test_engine_cycle_nests_the_flush_and_shares_its_dispatch(sink):
     assert sink.named("engine.idle")
 
 
+@pytest.mark.parametrize("on", [True, False], ids=["on", "off"])
+def test_handoff_span_wraps_the_cycles_submits(on, annotations):
+    """One packed cycle of k rhs hands them to the service inside exactly
+    one `engine.handoff`, itself inside `engine.dispatch`; off, none."""
+    rec = Sink()
+    if on:
+        tracing.enable(rec)
+    try:
+        eng = _engine(["a", "b", "c"], max_batch=8, flush_interval=30.0)
+        with eng:
+            futs = [eng.submit(mid, _rhs(i))
+                    for i, mid in enumerate(["a", "a", "b", "c"])]
+            eng.flush_now()
+            for f in futs:
+                assert f.result(timeout=30).mode == "analog"
+    finally:
+        tracing.disable()
+    if not on:
+        assert rec.spans == [] and "engine.handoff" not in annotations
+        return
+    (dispatch,) = rec.named("engine.dispatch")
+    (handoff,) = rec.named("engine.handoff")
+    (flush,) = rec.named("service.flush_all")
+    assert handoff[3]["rhs"] == 4
+    assert _inside(handoff, dispatch) and handoff[2] <= flush[1]
+
+
 def test_size_trigger_names_the_cycle(sink):
     eng = _engine(["a"], max_batch=2, flush_interval=30.0)
     with eng:
