@@ -16,10 +16,7 @@ switches *every* size and column to the nodal oracle instead.  The
 separate `oracle_main()` suite (run.py --only fig9_oracle; nightly) sweeps
 the n >= 64 regime where the first-order model leaves its validity
 envelope and writes artifacts/bench/fig9_oracle.json with matrix-level
-H-gap metrics plus solve-level medians under both models.  Metric keys
-deliberately avoid the `_us`/`_s`/`speedup` timing suffixes so
-diff_bench.py reports them without gating (accuracy deltas between
-nightlies are expected as seeds move).
+H-gap metrics plus solve-level medians under both models.
 """
 from __future__ import annotations
 

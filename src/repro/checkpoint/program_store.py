@@ -108,7 +108,6 @@ class ProgramStore:
             "signature": repr(signature),
             "a_sha256": _a_digest(a),
             "key_sha256": _key_digest(key),
-            "mode": solver.mode,
             "n": int(solver.n),
         }
         if extra:
@@ -139,6 +138,7 @@ class ProgramStore:
         Returns (solver, manifest_extra).  Raises StaleCheckpointError on
         identity mismatch, CheckpointCorruptionError on damaged files.
         The caller owns physics validation (canary vs stored trip).
+        A "mode" field that older checkpoints carry is ignored.
         """
         from repro.core.blockamc import ProgrammedSolver
 
@@ -162,8 +162,7 @@ class ProgramStore:
                 f"key")
         like = {"fin": template.finalized, "arena": template.arena}
         tree = restore_checkpoint(directory, step, like)
-        solver = ProgrammedSolver(tree["fin"], arena=tree["arena"],
-                                  mode=template.mode)
+        solver = ProgrammedSolver(tree["fin"], arena=tree["arena"])
         return solver, meta
 
     # -- damage hooks (tests / chaos) ---------------------------------------

@@ -100,9 +100,9 @@ CPU (eager) as before.  The arena mode is *float-tolerance* by design - the
 explicit inverse reassociates the INV solve and the divisor is applied
 before the tile dot instead of after - and is pinned against the finalized
 executor by the four-way equivalence suite (tests/test_fused_arena.py,
-TESTING.md).  It is the default `mode="fused"` on the serving surfaces
-(`ProgrammedSolver`, `SolverService`, `AnalogPreconditioner`);
-`mode="reference"` keeps the finalized path.
+TESTING.md).  It is the one executor of the serving surfaces
+(`ProgrammedSolver`, `SolverService`, `AnalogPreconditioner`); the
+reference executors stay plain functions that tests compare against.
 
 DESIGN - the packed instance axis (multi-tenant serving)
 ========================================================
@@ -136,7 +136,7 @@ layer adds the cross-tenant axis the per-matrix arena form lacks.
     last-ulp on ragged splits), and the packed Pallas megakernel
     (`kernels/arena_mvm.py arena_packed_apply`) grows an instance grid
     axis: grid (M, T) over an (M, S, K) arena stack, the whole fleet in
-    ONE pallas_call.  `sharding.partition.mc_packed_specs` shards the
+    ONE pallas_call.  `core.mc_sharding.mc_packed_specs` shards the
     instance axis over the mc mesh (`execute_arena_packed_sharded`).
 """
 from __future__ import annotations
@@ -150,6 +150,8 @@ import jax.numpy as jnp
 
 from repro.core import analog
 from repro.core.analog import AnalogConfig, CrossbarPair, TileGrid
+from repro.core.mc_sharding import (make_mc_mesh, mc_packed_specs,
+                                    mc_solve_specs)
 from repro.core.precision import F32_DOT as _F32
 from repro.runtime import tracing
 
@@ -934,10 +936,6 @@ def execute_finalized(fin: FinalizedPlan, b: jnp.ndarray) -> jnp.ndarray:
         else:  # pragma: no cover - finalize only emits the ops above
             raise ValueError(f"unknown schedule op {op!r}")
     return -fin.scale * analog.adc(regs[-1], cfg)
-
-
-_execute_finalized = jax.jit(execute_finalized)
-_execute_finalized_donated = jax.jit(execute_finalized, donate_argnums=(1,))
 
 
 # ---------------------------------------------------------------------------
@@ -1931,27 +1929,19 @@ class ProgrammedSolver:
     repeated solves never re-trace; `solve_many` pads the batch dim to the
     next power of two, so distinct queue lengths never re-trace either.
 
-    `mode` selects the executor (overridable per call): "fused" (default)
-    runs the arena-form single-dispatch executor - the serving fast path -
-    while "reference" runs the finalized schedule that is pinned
-    bit-for-bit against `execute_flat` (TESTING.md four-way contract).
+    Solves run the arena-form single-dispatch executor (`execute_arena`);
+    the finalized schedule it is pinned against (TESTING.md four-way
+    contract) is `execute_finalized(solver.finalized, b)`.
     """
 
     def __init__(self, fin: FinalizedPlan, arena: Optional[ArenaPlan] = None,
-                 mode: str = "fused", fplan: Optional[FlatPlan] = None,
+                 fplan: Optional[FlatPlan] = None,
                  parts: Optional[PartitionedSystem] = None,
                  stages: Optional[int] = None):
-        if mode not in ("reference", "fused"):
-            raise ValueError(f"mode must be 'reference' or 'fused', "
-                             f"got {mode!r}")
         self._fin = fin
         # arena compilation (explicit bucket inversions + layout analysis)
-        # is paid at programming time for fused-mode solvers and lazily on
-        # first fused use otherwise - reference-mode callers never pay it.
-        self._arena = arena
-        if self._arena is None and mode == "fused":
-            self._arena = compile_arena(fin)
-        self._mode = mode
+        # is paid here, at programming time
+        self._arena = compile_arena(fin) if arena is None else arena
         # Maintenance state: the flat plan (raw conductance stacks - drift
         # is a readout effect, so aging re-finalizes from here without
         # re-programming) and the partitioned system + resolved stage
@@ -1965,24 +1955,22 @@ class ProgrammedSolver:
 
     @classmethod
     def program(cls, a: jnp.ndarray, key: jax.Array, cfg: AnalogConfig,
-                stages: Optional[int] = None,
-                mode: str = "fused") -> "ProgrammedSolver":
+                stages: Optional[int] = None) -> "ProgrammedSolver":
         """Full programming flow for matrix A (one noise draw)."""
         parts = partition_system(a, cfg, stages)
         if stages is None:
             stages = required_stages(a.shape[0], cfg.array_size)
         return cls.from_plan(program_system(parts, key, cfg), cfg,
-                             mode=mode, parts=parts, stages=stages)
+                             parts=parts, stages=stages)
 
     @classmethod
     def from_plan(cls, plan: Union[SolvePlan, FlatPlan], cfg: AnalogConfig,
-                  mode: str = "fused",
                   parts: Optional[PartitionedSystem] = None,
                   stages: Optional[int] = None) -> "ProgrammedSolver":
         """Finalize an already-built plan (recursive or flat)."""
         fplan = plan if isinstance(plan, FlatPlan) else compile_plan(plan)
-        return cls(finalize(fplan, cfg), mode=mode, fplan=fplan,
-                   parts=parts, stages=stages)
+        return cls(finalize(fplan, cfg), fplan=fplan, parts=parts,
+                   stages=stages)
 
     @property
     def finalized(self) -> FinalizedPlan:
@@ -2026,9 +2014,8 @@ class ProgrammedSolver:
             raise ValueError("solver does not retain its flat plan "
                              "(checkpoint-restored?); aging unavailable")
         fin = finalize(self._fplan, self._fin.cfg, drift_t=drift_t)
-        arena = compile_arena(fin) if self._arena is not None else None
-        return ProgrammedSolver(fin, arena, self._mode, fplan=self._fplan,
-                                parts=self._parts, stages=self._stages)
+        return ProgrammedSolver(fin, fplan=self._fplan, parts=self._parts,
+                                stages=self._stages)
 
     def repaired(self, blocks, key: jax.Array,
                  drift_t=None) -> "ProgrammedSolver":
@@ -2051,11 +2038,9 @@ class ProgrammedSolver:
                                        self._fin.cfg, blocks, key,
                                        stages=self._stages)
         fin = splice_finalized(self._fin, fplan, changed, drift_t=drift_t)
-        arena = None
-        if self._arena is not None:
-            arena = splice_arena(self._arena, fin, fplan, changed)
-        return ProgrammedSolver(fin, arena, self._mode, fplan=fplan,
-                                parts=self._parts, stages=self._stages)
+        arena = splice_arena(self._arena, fin, fplan, changed)
+        return ProgrammedSolver(fin, arena, fplan=fplan, parts=self._parts,
+                                stages=self._stages)
 
     def placed(self, device) -> "ProgrammedSolver":
         """This solver with every array copied to `device`.
@@ -2065,18 +2050,12 @@ class ProgrammedSolver:
         copy can still age and be repaired in place."""
         fin, arena, fplan, parts = jax.device_put(
             (self._fin, self._arena, self._fplan, self._parts), device)
-        return ProgrammedSolver(fin, arena, self._mode, fplan=fplan,
-                                parts=parts, stages=self._stages)
+        return ProgrammedSolver(fin, arena, fplan=fplan, parts=parts,
+                                stages=self._stages)
 
     @property
     def arena(self) -> ArenaPlan:
-        if self._arena is None:
-            self._arena = compile_arena(self._fin)
         return self._arena
-
-    @property
-    def mode(self) -> str:
-        return self._mode
 
     @property
     def cfg(self) -> AnalogConfig:
@@ -2090,26 +2069,15 @@ class ProgrammedSolver:
     def num_arrays(self) -> int:
         return self._fin.num_arrays
 
-    def solve(self, b: jnp.ndarray, jit: bool = True,
-              mode: Optional[str] = None) -> jnp.ndarray:
+    def solve(self, b: jnp.ndarray) -> jnp.ndarray:
         """Solve A x = b for one (n,) rhs or an (n, k) batch.
 
-        mode=None uses the solver's default.  In "reference" mode,
-        jit=False runs the finalized schedule eagerly - op for op the same
-        numbers as `execute_flat`, bit-for-bit on CPU (the equivalence
-        contract); the jitted path lets XLA merge each level's same-shape
-        tile dots (float-tolerance equal).  "fused" mode runs the arena
-        executor - float-tolerance against the reference by design (see
-        the DESIGN note).
+        Runs the jitted arena executor - float-tolerance against the
+        reference executors by design (see the DESIGN note).
         """
-        mode = self._mode if mode is None else mode
-        if mode == "reference":
-            return (_execute_finalized if jit else execute_finalized)(
-                self._fin, b)
-        return (_execute_arena if jit else execute_arena)(self.arena, b)
+        return _execute_arena(self._arena, b)
 
     def solve_many(self, bs: jnp.ndarray, donate: bool = False,
-                   mode: Optional[str] = None,
                    pad_to_pow2: bool = True) -> jnp.ndarray:
         """Solve an (n, k) batch of right-hand sides in one fused call.
 
@@ -2128,13 +2096,8 @@ class ProgrammedSolver:
         if pad_to_pow2:
             bs, k = pad_rhs_pow2(bs)
         k_pad = bs.shape[1]
-        mode = self._mode if mode is None else mode
-        if mode == "reference":
-            fn = _execute_finalized_donated if donate else _execute_finalized
-            xs = fn(self._fin, bs)
-        else:
-            fn = _execute_arena_donated if donate else _execute_arena
-            xs = fn(self.arena, bs)
+        fn = _execute_arena_donated if donate else _execute_arena
+        xs = fn(self._arena, bs)
         return xs[:, :k] if k_pad > k else xs
 
 
@@ -2495,12 +2458,11 @@ def execute_arena_packed_sharded(pp: PackedArenaPlan, bs: jnp.ndarray,
     Each device runs its own shard of the packed fleet (operator stacks,
     scales and right-hand sides all carry the instance axis; the shared
     window-program metadata is replicated - specs from
-    `repro.sharding.partition.mc_packed_specs`).  num_instances must
-    divide evenly over the mesh axis.  mesh=None builds a 1-D mesh over
-    all local devices via `repro.launch.mesh.make_mc_mesh`.
+    `mc_packed_specs`).  num_instances must divide evenly over the mesh
+    axis.  mesh=None builds a 1-D mesh over all local devices via
+    `make_mc_mesh`.
     """
     if mesh is None:
-        from repro.launch.mesh import make_mc_mesh
         mesh = make_mc_mesh(axis_name=axis_name)
     n_shards = mesh.shape[axis_name]
     if pp.num_instances % n_shards:
@@ -2512,8 +2474,6 @@ def execute_arena_packed_sharded(pp: PackedArenaPlan, bs: jnp.ndarray,
 
 @partial(jax.jit, static_argnames=("mesh", "axis_name", "use_kernel"))
 def _sharded_packed_executor(pp, bs, mesh, axis_name, use_kernel):
-    from repro.sharding.partition import mc_packed_specs
-
     in_specs, out_specs = mc_packed_specs(pp, axis_name)
     mapped = jax.shard_map(
         lambda p, b: execute_arena_packed(p, b, use_kernel=use_kernel),
@@ -2592,8 +2552,6 @@ def _sharded_mc_executor(parts: PartitionedSystem, b: jnp.ndarray,
                          keys: jax.Array, cfg: AnalogConfig, mesh,
                          axis_name: str, mode: str) -> jnp.ndarray:
     """shard_map executor; cfg/mesh/axis are static so jit caches per combo."""
-    from repro.sharding.partition import mc_solve_specs
-
     in_specs, out_specs = mc_solve_specs(axis_name)
     mapped = jax.shard_map(
         lambda p, bb, kk: _mc_execute(p, bb, kk, cfg, mode),
@@ -2610,12 +2568,11 @@ def solve_batched_sharded(a: jnp.ndarray, b: jnp.ndarray, keys: jax.Array,
     Each device programs and solves its own shard of noise keys; the system
     matrix, partitioned pre-processing and right-hand sides are replicated.
     With mesh=None a 1-D mesh over all local devices is built via
-    `repro.launch.mesh.make_mc_mesh`.  num_keys must divide evenly over the
-    mesh axis.  mode="fused" runs each shard's keys through the arena
-    executor (same flag as `solve_batched`).
+    `make_mc_mesh`.  num_keys must divide evenly over the mesh axis.
+    mode="fused" runs each shard's keys through the arena executor (same
+    flag as `solve_batched`).
     """
     if mesh is None:
-        from repro.launch.mesh import make_mc_mesh
         mesh = make_mc_mesh(axis_name=axis_name)
     n_shards = mesh.shape[axis_name]
     if keys.shape[0] % n_shards:

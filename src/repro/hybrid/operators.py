@@ -50,10 +50,10 @@ class AnalogPreconditioner:
     paper's cost model promises, which is what makes it affordable *inside*
     a Krylov iteration.
 
-    `mode` picks the executor for the inner-loop apply: "fused" (default)
-    runs the arena-form single-dispatch executor (core/blockamc.py DESIGN
-    note) - the serving fast path - and "reference" the finalized schedule
-    it is float-tolerance-pinned against (TESTING.md four-way contract).
+    The inner-loop apply runs the arena-form single-dispatch executor
+    (core/blockamc.py DESIGN note), the serving fast path; the finalized
+    schedule it is float-tolerance-pinned against (TESTING.md four-way
+    contract) is `blockamc.execute_finalized(pre.fin, cols)`.
 
     Differentiability (TESTING.md "differentiable solver contract"): the
     apply is reverse-mode differentiable in both the input `v` and the
@@ -61,45 +61,39 @@ class AnalogPreconditioner:
     path routes through the arena executor's implicit-diff `custom_vjp`,
     so the backward pass is one transposed cascade, never a re-programming.
     The pytree split is load-bearing for that: `tree_flatten` keeps every
-    calibratable array in the children and only static metadata (`mode`,
-    and the plans' hashable level/spec tuples inside their own flattening)
-    in aux_data, so `jax.grad`/`jax.vmap` see exactly the differentiable
-    leaves and jit caches never retrace on a re-programmed instance
-    (pinned by the retrace-guard tests in tests/test_autodiff.py).
+    calibratable array in the children and only static metadata (the
+    plans' hashable level/spec tuples inside their own flattening; its
+    own aux_data is empty), so `jax.grad`/`jax.vmap` see exactly the
+    differentiable leaves and jit caches never retrace on a re-programmed
+    instance (pinned by the retrace-guard tests in tests/test_autodiff.py).
     """
 
     def __init__(self, fin: FinalizedPlan,
-                 aplan: Optional[ArenaPlan] = None, mode: str = "fused"):
+                 aplan: Optional[ArenaPlan] = None):
         self.fin = fin
-        self.mode = mode
-        if aplan is None and mode == "fused":
-            aplan = blockamc.compile_arena(fin)
-        self.aplan = aplan
+        self.aplan = blockamc.compile_arena(fin) if aplan is None else aplan
 
     def tree_flatten(self):
-        return (self.fin, self.aplan), (self.mode,)
+        return (self.fin, self.aplan), ()
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         obj = cls.__new__(cls)
         obj.fin, obj.aplan = children
-        obj.mode = aux[0]
         return obj
 
     @classmethod
     def program(cls, a: jnp.ndarray, key: jax.Array, cfg: AnalogConfig,
-                stages: Optional[int] = None,
-                mode: str = "fused") -> "AnalogPreconditioner":
+                stages: Optional[int] = None) -> "AnalogPreconditioner":
         """Full programming flow: partition, Schur, map + noise, finalize."""
         fplan = blockamc.compile_plan(blockamc.build_plan(a, key, cfg, stages))
-        return cls(blockamc.finalize(fplan, cfg), mode=mode)
+        return cls(blockamc.finalize(fplan, cfg))
 
     @classmethod
     def from_solver(cls, solver: "blockamc.ProgrammedSolver"
                     ) -> "AnalogPreconditioner":
-        """Share an already-programmed `ProgrammedSolver`'s plans + mode."""
-        aplan = solver.arena if solver.mode == "fused" else None
-        return cls(solver.finalized, aplan=aplan, mode=solver.mode)
+        """Share an already-programmed `ProgrammedSolver`'s plans."""
+        return cls(solver.finalized, aplan=solver.arena)
 
     @property
     def n(self) -> int:
@@ -114,21 +108,16 @@ class AnalogPreconditioner:
         """The analog substrate's dtype (set when the plan was built)."""
         return self.fin.scale.dtype
 
-    def _execute(self, cols: jnp.ndarray) -> jnp.ndarray:
-        """One executor dispatch on (n,) / (n, k) columns (mode-routed)."""
-        if self.mode == "fused" and self.aplan is not None:
-            return blockamc.execute_arena(self.aplan, cols)
-        return blockamc.execute_finalized(self.fin, cols)
-
     def __call__(self, v: jnp.ndarray) -> jnp.ndarray:
         """Apply M ~ A^-1 to (..., n); returns (..., n) in v's dtype."""
         n = self.fin.n
         if v.ndim == 1:
-            out = self._execute(v.astype(self.compute_dtype))
+            out = blockamc.execute_arena(self.aplan,
+                                         v.astype(self.compute_dtype))
             return out.astype(v.dtype)
         lead = v.shape[:-1]
         cols = v.reshape((-1, n)).T.astype(self.compute_dtype)  # (n, k)
-        out = self._execute(cols)
+        out = blockamc.execute_arena(self.aplan, cols)
         return out.T.reshape(lead + (n,)).astype(v.dtype)
 
     # LinearOperator-flavoured alias

@@ -32,6 +32,7 @@ import numpy as np
 from repro.core import blockamc
 from repro.core.analog import AnalogConfig
 from repro.core.blockamc import PartitionedSystem
+from repro.core.mc_sharding import make_mc_mesh, mc_refined_specs
 from repro.hybrid.krylov import KrylovResult, gmres, pcg
 from repro.hybrid.operators import AnalogPreconditioner, matvec_from_dense
 
@@ -200,14 +201,12 @@ def solve_fallback(a: jnp.ndarray, b: jnp.ndarray, *, method: str = "cg",
 
 def _refined_mc(a: jnp.ndarray, parts: PartitionedSystem, bt: jnp.ndarray,
                 keys: jax.Array, cfg: AnalogConfig, method: str, tol: float,
-                maxiter: int, restart: int, use_precond: bool,
-                mode: str = "fused"):
+                maxiter: int, restart: int, use_precond: bool):
     """Program + finalize + refine per noise key, vmapped over keys."""
 
     def one(k):
         fplan = blockamc.compile_plan(blockamc.program_system(parts, k, cfg))
-        precond = AnalogPreconditioner(blockamc.finalize(fplan, cfg),
-                                       mode=mode)
+        precond = AnalogPreconditioner(blockamc.finalize(fplan, cfg))
         return _refine(a, bt, precond, method, tol, maxiter, restart,
                        use_precond)
 
@@ -215,47 +214,41 @@ def _refined_mc(a: jnp.ndarray, parts: PartitionedSystem, bt: jnp.ndarray,
 
 
 @partial(jax.jit, static_argnames=("cfg", "method", "tol", "maxiter",
-                                   "restart", "use_precond", "mode"))
+                                   "restart", "use_precond"))
 def _refined_mc_jit(a, parts, bt, keys, cfg, method, tol, maxiter, restart,
-                    use_precond, mode):
+                    use_precond):
     return _refined_mc(a, parts, bt, keys, cfg, method, tol, maxiter,
-                       restart, use_precond, mode)
+                       restart, use_precond)
 
 
 def solve_refined_batched(a: jnp.ndarray, b: jnp.ndarray, keys: jax.Array,
                           cfg: AnalogConfig, *, stages: Optional[int] = None,
                           method: str = "cg", tol: float = 1e-10,
                           maxiter: int = 400, restart: int = 32,
-                          use_precond: bool = True,
-                          mode: str = "fused") -> KrylovResult:
+                          use_precond: bool = True) -> KrylovResult:
     """Monte-Carlo hybrid solve: one refined solve per noise key, one jit.
 
     Every key programs its own noisy preconditioner (key-independent digital
     pre-processing hoisted via `partition_system`) and refines the same
     right-hand sides.  Returns a KrylovResult with a leading (num_keys, ...)
     axis on every field; `b` may be (n,) or (n, k) (x comes back as
-    (num_keys, n) / (num_keys, k, n)).  `mode` picks the seed/
-    preconditioner executor ("fused" arena default / "reference").
+    (num_keys, n) / (num_keys, k, n)).
     """
     parts = blockamc.partition_system(a, cfg, stages)
     bt = (b if b.ndim == 1 else b.T).astype(a.dtype)
     return _refined_mc_jit(a, parts, bt, keys, cfg, method, float(tol),
-                           int(maxiter), int(restart), bool(use_precond),
-                           mode)
+                           int(maxiter), int(restart), bool(use_precond))
 
 
 @partial(jax.jit, static_argnames=("cfg", "method", "tol", "maxiter",
                                    "restart", "use_precond", "mesh",
-                                   "axis_name", "mode"))
+                                   "axis_name"))
 def _refined_mc_sharded(a, parts, bt, keys, cfg, method, tol, maxiter,
-                        restart, use_precond, mesh, axis_name, mode):
-    from repro.sharding.partition import mc_refined_specs
-
+                        restart, use_precond, mesh, axis_name):
     in_specs, out_specs = mc_refined_specs(axis_name)
     mapped = jax.shard_map(
         lambda aa, pp, bb, kk: _refined_mc(aa, pp, bb, kk, cfg, method, tol,
-                                           maxiter, restart, use_precond,
-                                           mode),
+                                           maxiter, restart, use_precond),
         mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
     return mapped(a, parts, bt, keys)
 
@@ -266,8 +259,7 @@ def solve_refined_batched_sharded(a: jnp.ndarray, b: jnp.ndarray,
                                   method: str = "cg", tol: float = 1e-10,
                                   maxiter: int = 400, restart: int = 32,
                                   use_precond: bool = True, mesh=None,
-                                  axis_name: str = "mc",
-                                  mode: str = "fused") -> KrylovResult:
+                                  axis_name: str = "mc") -> KrylovResult:
     """`solve_refined_batched` with the noise-key axis sharded over a mesh.
 
     Each device programs and refines its own shard of noisy preconditioners;
@@ -276,7 +268,6 @@ def solve_refined_batched_sharded(a: jnp.ndarray, b: jnp.ndarray,
     num_keys must divide evenly over the mesh axis.
     """
     if mesh is None:
-        from repro.launch.mesh import make_mc_mesh
         mesh = make_mc_mesh(axis_name=axis_name)
     n_shards = mesh.shape[axis_name]
     if keys.shape[0] % n_shards:
@@ -287,4 +278,4 @@ def solve_refined_batched_sharded(a: jnp.ndarray, b: jnp.ndarray,
     bt = (b if b.ndim == 1 else b.T).astype(a.dtype)
     return _refined_mc_sharded(a, parts, bt, keys, cfg, method, float(tol),
                                int(maxiter), int(restart), bool(use_precond),
-                               mesh, axis_name, mode)
+                               mesh, axis_name)

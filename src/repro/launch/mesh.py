@@ -20,13 +20,3 @@ def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over host (CPU) devices for tests."""
     return jax.make_mesh((data, model), ("data", "model"))
 
-
-def make_mc_mesh(n_devices: int | None = None, axis_name: str = "mc"):
-    """1-D mesh for sharding a Monte-Carlo key axis (blockamc sweeps).
-
-    Defaults to all local devices; `solve_batched_sharded` gives every
-    device its own shard of independent noise keys.
-    """
-    if n_devices is None:
-        n_devices = jax.device_count()
-    return jax.make_mesh((n_devices,), (axis_name,))

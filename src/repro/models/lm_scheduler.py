@@ -10,10 +10,8 @@ validity masks already exclude entries past the new position; recurrent
 SSM/RG-LRU states are zeroed explicitly).
 
 The host loop does slot bookkeeping; the per-token step stays one jitted
-SPMD program - the standard split in production engines.  The solver
-analogue of this discipline is `repro.serve.scheduler
-.PackedSolverScheduler` (this module used to share a file with it; the LM
-half moved here with the rest of the retired `serve.Engine` surface).
+SPMD program - the standard split in production engines.  The LM half
+moved here with the rest of the retired `serve.Engine` surface.
 """
 from __future__ import annotations
 

@@ -5,7 +5,6 @@ The LM generation engine that used to live here (`serve.Engine`,
 `repro.models.serve_step` - this package is the *solver* serving stack.
 """
 from repro.serve.solver_service import SolverService, MatrixStats  # noqa: F401
-from repro.serve.scheduler import PackedSolverScheduler  # noqa: F401
 from repro.serve.async_engine import (  # noqa: F401
     AsyncSolverEngine, BackpressureError, DeadlineExceededError,
     EngineError, EngineStats, EngineStoppedError, SolveResult)

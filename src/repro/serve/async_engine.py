@@ -3,11 +3,11 @@
 `AsyncSolverEngine` turns the synchronous, caller-driven `SolverService`
 into a real serving engine (the ROADMAP "millions of users" tentpole):
 
-* **Worker-owned device** (the MaxText JetThread / queue-handoff split,
-  echoed in `serve/scheduler.py`'s host-loop/jitted-step discipline): ONE
-  background thread owns every device-touching operation - programming,
-  packed dispatch, health checks, recovery.  Callers only touch host-side
-  admission state under a lock, so no jax dispatch ever races another.
+* **Worker-owned device** (the MaxText JetThread / queue-handoff split):
+  ONE background thread owns every device-touching operation -
+  programming, packed dispatch, health checks, recovery.  Callers only
+  touch host-side admission state under a lock, so no jax dispatch ever
+  races another.
 * **Deadline-aware futures**: `submit` returns a `concurrent.futures
   .Future` resolving to a `SolveResult` (answer + serving metadata) or a
   *typed* error - `DeadlineExceededError`, `EngineStoppedError` - never a
@@ -45,7 +45,7 @@ Determinism: `runtime.chaos.ChaosInjector` hooks all three fault surfaces
 (scripted dispatch exceptions, scripted latency, device faults via the
 `NonidealConfig` physics knobs) keyed on the engine's dispatch counter,
 so the whole failover ladder is exercised deterministically in tier-1
-tests and the `benchmarks/engine_bench.py` chaos smoke.
+tests.
 """
 from __future__ import annotations
 
@@ -226,7 +226,7 @@ class AsyncSolverEngine:
         # drift-aware self-healing (see serve/maintenance.py DESIGN note):
         # `clock` turns on simulated device aging; `scrub=False` keeps the
         # aging but disables the proactive scrub/repair loop (the reactive
-        # baseline the maintenance tests and maint_bench compare against).
+        # baseline the maintenance tests compare against).
         # `repair_gate` is a lock-free callable the fleet uses to stagger
         # repair windows (it is read inside the worker's wait predicate
         # with the engine lock held, so it MUST NOT take other locks);
@@ -527,8 +527,7 @@ class AsyncSolverEngine:
 
         "maintenance" carries the per-matrix drift gauges (trend slope,
         predicted time-to-trip, scrub backlog, blocks repaired, ...) -
-        report-only observability, surfaced through `FleetStats` and the
-        maint_bench artifact keys."""
+        report-only observability, surfaced through `FleetStats`."""
         t_now = self.clock.now() if self.clock is not None else 0.0
         with self._lock:
             canaries = {mid: st.last_canary

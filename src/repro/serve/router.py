@@ -61,7 +61,7 @@ ORIGINAL trip threshold (`engine.install`).  Only when the checkpoint is
 stale (signature/hash/key mismatch), corrupt (manifest cross-check), or
 physically bad (canary rejection) does it fall back to full
 re-programming.  Restore-vs-reprogram times are recorded per recovery in
-`FleetStats` - the measurable ratio `benchmarks/router_bench.py` pins.
+`FleetStats`.
 """
 from __future__ import annotations
 

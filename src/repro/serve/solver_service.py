@@ -22,9 +22,8 @@ double-counted.
 
 Deliberately synchronous and small - the batching discipline and the
 program/solve cost split are the point; transport and scheduling live a
-layer up (cf. serve/engine.py for the LM analogue and
-serve/scheduler.py's `PackedSolverScheduler` for the continuous-batching
-flush policy over this service).
+layer up, in serve/async_engine.py's `AsyncSolverEngine` (its size, age
+and deadline flush triggers drive `flush_all`).
 """
 from __future__ import annotations
 
@@ -81,16 +80,12 @@ class SolverService:
     conductance mapping, operator finalization, arena compilation and the
     first jit) exactly once per matrix; `solve` answers immediately;
     `submit` + `flush` batch queued right-hand sides into one fused
-    multi-RHS solve.  mode="fused" (default) serves from the arena-form
-    single-dispatch executor; mode="reference" keeps the finalized
-    schedule (TESTING.md four-way contract).
+    multi-RHS solve, served by the arena-form single-dispatch executor.
     """
 
-    def __init__(self, cfg: AnalogConfig, stages: Optional[int] = None,
-                 mode: str = "fused"):
+    def __init__(self, cfg: AnalogConfig, stages: Optional[int] = None):
         self.cfg = cfg
         self.stages = stages
-        self.mode = mode   # "fused" arena executor (default) / "reference"
         self._solvers: Dict[str, ProgrammedSolver] = {}
         self._dense: Dict[str, jnp.ndarray] = {}
         self._queues: Dict[str, List[jnp.ndarray]] = {}
@@ -148,8 +143,7 @@ class SolverService:
         cfg = cfg if cfg is not None else self.cfg
         key = key if key is not None else jax.random.PRNGKey(0)
         t0 = time.perf_counter()
-        solver = ProgrammedSolver.program(a, key, cfg, self.stages,
-                                          mode=self.mode)
+        solver = ProgrammedSolver.program(a, key, cfg, self.stages)
         # Warm the jitted executor (single-rhs and smallest flush batch) as
         # part of programming time; solve_many pads to powers of two, so
         # each further batch-shape compile happens at most once per
@@ -489,8 +483,7 @@ class SolverService:
         delivery is a free numpy view) -
         uniformly, including the fallback paths, so the result type never
         depends on how many tenants happened to be pending.
-        Single-tenant buckets and mode="reference" services fall back to
-        the per-matrix `flush` (the packed executor is arena-form only).
+        Single-tenant buckets take the per-matrix `flush` path.
         """
         if matrix_ids is None:
             ids = tuple(self._queues)
@@ -516,14 +509,14 @@ class SolverService:
         # every queue and counter exactly as it was: all-or-nothing.
         staged = []                     # (bucket ids, per-tenant ks, xs)
         for sig, bucket in buckets.items():
-            if len(bucket) == 1 or self.mode != "fused":
-                # single-tenant / reference fallback: the same per-matrix
-                # solve body `flush` runs, staged like the packed buckets
-                for mid in bucket:
-                    xs = self._solve_queue(mid)
-                    with tracing.span("service.fetch"):
-                        xs_host = np.asarray(xs)[None]
-                    staged.append(([mid], [len(self._queues[mid])], xs_host))
+            if len(bucket) == 1:
+                # single tenant: the same per-matrix solve body `flush`
+                # runs, staged like the packed buckets
+                (mid,) = bucket
+                xs = self._solve_queue(mid)
+                with tracing.span("service.fetch"):
+                    xs_host = np.asarray(xs)[None]
+                staged.append((bucket, [len(self._queues[mid])], xs_host))
                 continue
             ks = [len(self._queues[mid]) for mid in bucket]
             k_max = max(ks)

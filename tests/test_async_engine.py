@@ -111,6 +111,41 @@ def test_flush_now_forces_early_dispatch():
         f.result(timeout=60)          # without the flush this would sit 60s
 
 
+@pytest.mark.parametrize("full", ["base", "override"])
+def test_size_trigger_fires_one_signature_bucket(full):
+    """A signature bucket dispatches the moment it holds max_batch rhs
+    while a bucket of a second signature (a per-matrix cfg override)
+    keeps filling; the stragglers answer on demand, each with its own
+    tenant's numbers."""
+    svc = _service()
+    eng = AsyncSolverEngine(svc, max_batch=4, flush_interval=60.0)
+    _program(eng, ["b0", "b1"])
+    eng.program("o0", wishart(jax.random.fold_in(KEY, 2), N),
+                jax.random.fold_in(KEY, 102),
+                cfg=CFG.with_(nonideal=NonidealConfig(sigma=0.03)))
+    filled, waiting = ((["b0", "b1"], ["o0"]) if full == "base"
+                       else (["o0"], ["b0", "b1"]))
+    with eng:
+        stay = [(mid, _rhs(10 + i)) for i, mid in enumerate(waiting)]
+        stay = [(mid, b, eng.submit(mid, b)) for mid, b in stay]
+        fire = [(filled[i % len(filled)], _rhs(i)) for i in range(4)]
+        fire = [(mid, b, eng.submit(mid, b)) for mid, b in fire]
+        for mid, b, f in fire:
+            f.result(timeout=60)
+        assert not any(f.done() for _, _, f in stay)
+        assert eng.pending() == len(stay)
+        eng.flush_now()
+        for mid, b, f in stay:
+            f.result(timeout=60)
+    for mid, b, f in fire + stay:
+        r = f.result()
+        assert r.matrix_id == mid and r.mode == "analog"
+        np.testing.assert_allclose(
+            r.x, np.asarray(svc.solver(mid).solve(jnp.asarray(b))),
+            rtol=1e-5, atol=1e-6)
+    assert eng.stats.answered == 4 + len(stay)
+
+
 # --------------------------- deadlines / SLOs -----------------------------
 
 def test_expired_request_is_shed_with_typed_error():

@@ -189,7 +189,7 @@ def test_backward_pass_reprograms_nothing():
         b = random_rhs(KB, N).astype(jnp.float64)
 
         def loss(bb):
-            return jnp.sum(solver.solve(bb, jit=False))
+            return jnp.sum(blockamc.execute_arena(solver.arena, bb))
 
         prims = _collect_primitives(
             jax.make_jaxpr(jax.grad(loss))(b).jaxpr, set())

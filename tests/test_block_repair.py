@@ -11,7 +11,7 @@ untouched slice stays bit-for-bit what it was.  In particular repairing
 This is what makes block repair a safe maintenance primitive: a repaired
 plan is never a third artifact to validate - it IS the re-programmed
 plan, restricted to the degraded fraction (cost scales with #blocks, not
-n^2 - benchmarks/maint_bench.py pins the ratio).
+n^2).
 
 Hypothesis drives (stages, nonideality, subset seed) when installed; a
 fixed parametrized sweep keeps tier-1 coverage without it (the

@@ -85,17 +85,22 @@ def test_four_way_equivalence(n, stages, tag, make_cfg, multi_rhs):
                                rtol=2e-4, atol=2e-5)
 
 
-@pytest.mark.parametrize("mode", ["reference", "fused"])
-def test_jitted_solver_matches_eager(mode):
-    """ProgrammedSolver's shared jitted executors == the eager schedule
-    at float tolerance, for both modes, single and multi rhs."""
+@pytest.mark.parametrize("leg", ["reference", "fused"])
+def test_jitted_solver_matches_eager(leg):
+    """Jitted executors == the eager schedule at float tolerance, single
+    and multi rhs: the finalized reference executor on the solver's plan,
+    and the solver's own (arena) solve."""
     n, stages = 32, 2
     cfg = AnalogConfig(array_size=8, nonideal=NonidealConfig(sigma=0.05))
     a = wishart(KA, n)
     solver = blockamc.ProgrammedSolver.program(a, KN, cfg, stages=stages)
     for b in (random_rhs(KB, n), jax.random.normal(KB, (n, 5))):
-        x_eager = solver.solve(b, jit=False, mode=mode)
-        x_jit = solver.solve(b, mode=mode)
+        if leg == "reference":
+            x_eager = blockamc.execute_finalized(solver.finalized, b)
+            x_jit = jax.jit(blockamc.execute_finalized)(solver.finalized, b)
+        else:
+            x_eager = blockamc.execute_arena(solver.arena, b)
+            x_jit = solver.solve(b)
         np.testing.assert_allclose(np.asarray(x_jit), np.asarray(x_eager),
                                    rtol=1e-5, atol=1e-6)
 
@@ -107,7 +112,8 @@ def test_fused_solves_the_system():
     a = wishart(KA, n)
     b = random_rhs(KB, n)
     solver = blockamc.ProgrammedSolver.program(a, KN, cfg, stages=2)
-    assert solver.mode == "fused"
+    # the arena plan is compiled at programming time, not on first solve
+    assert isinstance(solver.arena, blockamc.ArenaPlan)
     np.testing.assert_allclose(np.asarray(solver.solve(b)),
                                np.asarray(jnp.linalg.solve(a, b)),
                                rtol=1e-3, atol=1e-4)
@@ -156,7 +162,7 @@ def test_solve_many_does_not_retrace_across_k():
 def test_mc_fused_matches_reference_mode():
     """solve_batched(mode='fused') == reference mode at float tolerance,
     plain and sharded (per-key finalize + arena-compile under vmap)."""
-    from repro.launch.mesh import make_mc_mesh
+    from repro.core.mc_sharding import make_mc_mesh
     n = 32
     cfg = AnalogConfig(array_size=16, nonideal=NonidealConfig(sigma=0.05))
     a = wishart(KA, n)
@@ -174,23 +180,23 @@ def test_mc_fused_matches_reference_mode():
 
 
 def test_preconditioner_modes_agree():
-    """AnalogPreconditioner fused apply == reference apply (float tol),
-    and the pytree round-trips with both plans attached."""
+    """AnalogPreconditioner's (arena) apply == the finalized reference
+    executor on its plan (float tol), and the pytree round-trips with
+    both plans attached."""
     from repro.hybrid import AnalogPreconditioner
     n = 32
     cfg = AnalogConfig(array_size=16, nonideal=NonidealConfig(sigma=0.02))
     a = wishart(KA, n)
     pre_f = AnalogPreconditioner.program(a, KN, cfg, stages=1)
-    pre_r = AnalogPreconditioner.program(a, KN, cfg, stages=1,
-                                         mode="reference")
     v = jax.random.normal(KB, (5, n))
-    np.testing.assert_allclose(np.asarray(pre_f(v)), np.asarray(pre_r(v)),
+    x_ref = blockamc.execute_finalized(pre_f.fin, v.T).T
+    np.testing.assert_allclose(np.asarray(pre_f(v)), np.asarray(x_ref),
                                rtol=2e-4, atol=2e-5)
     leaves, td = jax.tree_util.tree_flatten(pre_f)
     pre_2 = jax.tree_util.tree_unflatten(td, leaves)
     np.testing.assert_array_equal(np.asarray(pre_f(v)),
                                   np.asarray(pre_2(v)))
-    hash(td)    # jit cache key: aux (mode + plan metadata) stays hashable
+    hash(td)    # jit cache key: the plans' static metadata stays hashable
 
 
 def test_arena_plan_is_pytree():

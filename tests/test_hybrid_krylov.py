@@ -183,7 +183,7 @@ def test_differential_refined_vs_numpy(cond, sigma):
 # ------------------- Monte-Carlo batched + sharded ------------------------
 
 def test_refined_batched_matches_per_key_and_sharded():
-    from repro.launch.mesh import make_mc_mesh
+    from repro.core.mc_sharding import make_mc_mesh
     with jax.enable_x64():
         n = 32
         a = wishart_with_cond(KA, n, 1e2, dtype=jnp.float64)

@@ -10,8 +10,7 @@ reduction nor changes the per-slice dot kernel), last-ulp float tolerance
 on ragged odd splits and under jit (XLA dot merging).  On top sit the serving paths: `SolverService.flush_all`
 groups pending queues by `plan_signature`, pads ragged per-tenant queue
 lengths to one shared power-of-two width and scatters per-tenant answers
-back, and `PackedSolverScheduler` drives that flush with a
-continuous-batching admission policy.
+back; `AsyncSolverEngine` drives that flush (tests/test_async_engine.py).
 
 Signature bucketing properties (same signature => identical schedule +
 arena layout) live in tests/test_plan_properties.py; packed megakernel
@@ -26,7 +25,7 @@ from repro.core import blockamc
 from repro.core.analog import AnalogConfig
 from repro.core.nonideal import NonidealConfig
 from repro.data.matrices import wishart
-from repro.serve import PackedSolverScheduler, SolverService
+from repro.serve import SolverService
 
 KEY = jax.random.PRNGKey(23)
 KA, KB, KN = jax.random.split(KEY, 3)
@@ -166,7 +165,7 @@ def test_packed_kernel_rejects_nonuniform():
 
 def test_packed_sharded_matches_unsharded():
     """Instance axis over a (1-device) mc mesh == the plain packed path."""
-    from repro.launch.mesh import make_mc_mesh
+    from repro.core.mc_sharding import make_mc_mesh
     cfg = AnalogConfig(array_size=8, nonideal=NonidealConfig(sigma=0.05))
     m, n = 4, 16
     _, _, aps = _fleet(m, n, cfg, 1)
@@ -224,7 +223,7 @@ print('OK', xs_sh.shape)
 
 
 # ---------------------------------------------------------------------------
-# SolverService.flush_all + scheduler
+# SolverService.flush_all
 # ---------------------------------------------------------------------------
 
 N = 32
@@ -321,20 +320,53 @@ def test_flush_all_mixed_signatures_and_singletons():
         svc.flush_all(matrix_ids=["big", "nope"])
 
 
-def test_flush_all_reference_mode_falls_back():
-    """mode="reference" services keep the finalized executor: flush_all
-    still answers everything (per-matrix path, no packing)."""
-    svc = SolverService(CFG, stages=1, mode="reference")
-    for i in range(2):
-        svc.program(f"m{i}", wishart(jax.random.fold_in(KA, i), N),
+def test_flush_all_failed_dispatch_is_all_or_nothing(monkeypatch):
+    """A packed dispatch that raises inside flush_all - here the second
+    of two signature buckets, after the first has already run - leaves
+    every queue and counter as it was, and a plain retry answers every
+    tenant with its own solver's numbers (the engine's retry ladder
+    re-flushes the same batch on this contract)."""
+    import repro.serve.solver_service as ss
+
+    svc = SolverService(CFG, stages=1)
+    sizes = {"a0": 32, "a1": 32, "c0": 16, "c1": 16}
+    for i, (mid, n) in enumerate(sizes.items()):
+        svc.program(mid, wishart(jax.random.fold_in(KA, i), n),
                     jax.random.fold_in(KN, i))
-    for i in range(2):
-        svc.submit(f"m{i}", jax.random.normal(jax.random.fold_in(KB, i),
-                                              (N,)))
-    out = svc.flush_all()
-    assert set(out) == {"m0", "m1"}
-    assert all(out[mid].shape == (N, 1) for mid in out)
-    assert not svc._packs                         # nothing was packed
+    assert svc.signature("a0") != svc.signature("c0")
+    cols = {mid: [jax.random.normal(jax.random.fold_in(KB, 10 * i + j),
+                                    (n,)) for j in range(i % 2 + 1)]
+            for i, (mid, n) in enumerate(sizes.items())}
+    for mid, bs in cols.items():
+        for b in bs:
+            svc.submit(mid, b)
+
+    real = ss._execute_arena_packed_selected_donated
+    calls = {"n": 0}
+
+    def exploding(pp, idx, bs):
+        calls["n"] += 1
+        if calls["n"] == 2:
+            raise RuntimeError("injected device OOM")
+        return real(pp, idx, bs)
+
+    monkeypatch.setattr(ss, "_execute_arena_packed_selected_donated",
+                        exploding)
+    with pytest.raises(RuntimeError, match="injected device OOM"):
+        svc.flush_all()
+    assert calls["n"] == 2                       # one bucket had run
+    for mid, bs in cols.items():
+        assert svc.pending(mid) == len(bs)
+        assert svc.stats(mid).rhs_served == 0
+
+    out = svc.flush_all()                        # plain retry, no reset
+    for mid, bs in cols.items():
+        expected = jnp.stack([svc.solver(mid).solve(b) for b in bs], axis=1)
+        np.testing.assert_allclose(np.asarray(out[mid]),
+                                   np.asarray(expected),
+                                   rtol=1e-5, atol=1e-6)
+        assert svc.pending(mid) == 0
+        assert svc.stats(mid).rhs_served == len(bs)
 
 
 def test_reprogram_invalidates_pack_cache():
@@ -603,142 +635,3 @@ def test_new_tenant_rebuilds_resident_stack(sink):
     (entry,) = svc._packs.values()
     assert tuple(entry[0]) == (*ids, "m3")
     assert entry[1].num_instances == 4
-
-
-def test_scheduler_continuous_batching_flush():
-    """PackedSolverScheduler fires a signature bucket the moment it holds
-    max_batch pending rhs, leaves other buckets filling, and drains the
-    stragglers on demand."""
-    svc, ids = _service(m=3)
-    sched = PackedSolverScheduler(svc, max_batch=4)
-    b = [jax.random.normal(jax.random.fold_in(KB, j), (N,))
-         for j in range(6)]
-    t0 = sched.submit(ids[0], b[0])
-    t1 = sched.submit(ids[0], b[1])
-    t2 = sched.submit(ids[1], b[2])
-    assert sched.pending() == 3 and not sched.ready(t0)
-    t3 = sched.submit(ids[2], b[3])               # 4th pending -> flush
-    assert sched.pending() == 0
-    for t, bj in zip((t0, t1, t2, t3), b[:4]):
-        assert sched.ready(t)
-    np.testing.assert_allclose(np.asarray(sched.result(t1)),
-                               np.asarray(svc.solver(ids[0]).solve(b[1])),
-                               rtol=1e-5, atol=1e-6)
-    assert not sched.ready(t1)                    # one-shot delivery
-    # stragglers drain explicitly; tickets stay unique across generations
-    t4 = sched.submit(ids[1], b[4])
-    assert t4 == (ids[1], 1) and sched.pending() == 1
-    sched.drain()
-    assert sched.pending() == 0 and sched.ready(t4)
-    np.testing.assert_allclose(np.asarray(sched.result(t4)),
-                               np.asarray(svc.solver(ids[1]).solve(b[4])),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_scheduler_detects_external_queue_writes():
-    """The scheduler owns its service's queues: ticket->column mapping is
-    per-tenant submission order, so a direct service.submit alongside a
-    scheduler must fail loudly at delivery, never mis-assign answers."""
-    svc, ids = _service(m=2)
-    sched = PackedSolverScheduler(svc, max_batch=8)
-    b = jax.random.normal(KB, (N,))
-    t_stale = sched.submit(ids[0], b)
-    svc.submit(ids[0], b)          # bypasses the scheduler
-    with pytest.raises(RuntimeError, match="outside this scheduler"):
-        sched.drain()
-    # the violated tenant's open tickets are void, its counters resynced:
-    # a caller that catches the error and keeps going gets fresh answers
-    # on fresh tickets, never a later flush landing on the stale one
-    assert not sched.ready(t_stale) and sched.pending() == 0
-    b2 = jax.random.normal(jax.random.fold_in(KB, 9), (N,))
-    t_new = sched.submit(ids[0], b2)
-    sched.drain()
-    assert not sched.ready(t_stale) and sched.ready(t_new)
-    np.testing.assert_allclose(np.asarray(sched.result(t_new)),
-                               np.asarray(svc.solver(ids[0]).solve(b2)),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_scheduler_survives_injected_dispatch_failure(monkeypatch):
-    """Exception-safety audit (ISSUE satellite): a dispatch that raises
-    mid-drain leaves the service queues, the per-signature counters and
-    every open ticket exactly as they were - `check_consistency` holds
-    after the failure, a plain retry succeeds, and every ticket delivers
-    its own tenant's numbers."""
-    svc, ids = _service(m=3)
-    sched = PackedSolverScheduler(svc, max_batch=8)
-    b = [jax.random.normal(jax.random.fold_in(KB, j), (N,))
-         for j in range(5)]
-    tickets = [sched.submit(ids[j % 3], bj) for j, bj in enumerate(b)]
-    sched.check_consistency()
-
-    # inject: the packed executor dies on its next invocation only
-    real = blockamc._execute_arena_packed_selected_donated
-    blows = {"left": 1}
-
-    def exploding(pp, idx, bs):
-        if blows["left"]:
-            blows["left"] -= 1
-            raise RuntimeError("injected device OOM")
-        return real(pp, idx, bs)
-
-    monkeypatch.setattr(blockamc, "_execute_arena_packed_selected_donated",
-                        exploding)
-    import repro.serve.solver_service as ss
-    monkeypatch.setattr(ss, "_execute_arena_packed_selected_donated",
-                        exploding)
-
-    with pytest.raises(RuntimeError, match="injected device OOM"):
-        sched.drain()
-    # all-or-nothing: nothing delivered, nothing dropped, counters intact
-    assert sched.pending() == 5
-    assert all(svc.pending(mid) > 0 for mid in ids)
-    assert not any(sched.ready(t) for t in tickets)
-    sched.check_consistency()
-    assert all(svc.stats(mid).rhs_served == 0 for mid in ids)
-
-    sched.drain()                                # plain retry, no reset
-    sched.check_consistency()
-    assert sched.pending() == 0
-    for t, bj in zip(tickets, b):
-        assert sched.ready(t)
-        np.testing.assert_allclose(np.asarray(sched.result(t)),
-                                   np.asarray(svc.solver(t[0]).solve(bj)),
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_scheduler_failure_on_triggering_submit_keeps_ticket(monkeypatch):
-    """The same injected failure on the submit that *triggers* a flush:
-    the submit raises, but its rhs and ticket stay queued and the next
-    drain answers them (nothing queued is ever dropped)."""
-    svc, ids = _service(m=2)
-    sched = PackedSolverScheduler(svc, max_batch=2)
-    b0 = jax.random.normal(KB, (N,))
-    b1 = jax.random.normal(jax.random.fold_in(KB, 1), (N,))
-    t0 = sched.submit(ids[0], b0)
-
-    real = blockamc._execute_arena_packed_selected_donated
-    blows = {"left": 1}
-
-    def exploding(pp, idx, bs):
-        if blows["left"]:
-            blows["left"] -= 1
-            raise RuntimeError("injected")
-        return real(pp, idx, bs)
-
-    monkeypatch.setattr(blockamc, "_execute_arena_packed_selected_donated",
-                        exploding)
-    import repro.serve.solver_service as ss
-    monkeypatch.setattr(ss, "_execute_arena_packed_selected_donated",
-                        exploding)
-
-    with pytest.raises(RuntimeError, match="injected"):
-        sched.submit(ids[1], b1)                 # 2nd pending -> flush dies
-    t1 = (ids[1], 0)                             # its ticket is well-defined
-    sched.check_consistency()
-    assert sched.pending() == 2
-    sched.drain()
-    for t, bj in ((t0, b0), (t1, b1)):
-        np.testing.assert_allclose(np.asarray(sched.result(t)),
-                                   np.asarray(svc.solver(t[0]).solve(bj)),
-                                   rtol=1e-5, atol=1e-6)

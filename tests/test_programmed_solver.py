@@ -3,13 +3,13 @@
 The reference-side contract (TESTING.md four-way contract, legs 1-3):
 `finalize` precomputes exactly the operators `execute_flat` derives per
 call - same LU factors, same per-tile effective matrices, same
-accumulation order - so the finalized executor (mode="reference") matches
-the flat one bit-for-bit on CPU when both run the schedule eagerly (and
-the flat one in turn matches the recursive reference).  The jitted
-reference path lets XLA merge each level's same-shape tile dots, which
-reassociates final-ulp rounding only: float-tolerance equal.  The solver's
-default mode="fused" arena executor (leg 4) is pinned in
-tests/test_fused_arena.py.
+accumulation order - so the finalized executor (`execute_finalized` on
+`solver.finalized`) matches the flat one bit-for-bit on CPU when both run
+the schedule eagerly (and the flat one in turn matches the recursive
+reference).  The jitted reference path lets XLA merge each level's
+same-shape tile dots, which reassociates final-ulp rounding only:
+float-tolerance equal.  The solver's own arena executor (leg 4) is pinned
+in tests/test_fused_arena.py.
 """
 import os
 import subprocess
@@ -29,6 +29,8 @@ from repro.serve import SolverService
 
 KEY = jax.random.PRNGKey(11)
 KA, KB, KN = jax.random.split(KEY, 3)
+
+_execute_finalized = jax.jit(blockamc.execute_finalized)
 
 # Acceptance grid: n in {8, 17, 64} x stages {0, 1, 2}, ragged splits
 # included, for device variation, first-order wire model and finite
@@ -57,7 +59,7 @@ def test_finalized_matches_flat_bitwise(n, stages, tag, make_cfg):
                                                       stages=stages))
     x_flat = blockamc.execute_flat(fplan, b, cfg)
     solver = blockamc.ProgrammedSolver.from_plan(fplan, cfg)
-    x_fin = solver.solve(b, jit=False, mode="reference")
+    x_fin = blockamc.execute_finalized(solver.finalized, b)
     if jax.default_backend() == "cpu":
         # precomputed operators == per-call derivations, op order identical
         np.testing.assert_array_equal(np.asarray(x_flat), np.asarray(x_fin))
@@ -65,7 +67,7 @@ def test_finalized_matches_flat_bitwise(n, stages, tag, make_cfg):
         np.testing.assert_allclose(np.asarray(x_flat), np.asarray(x_fin),
                                    rtol=1e-6, atol=1e-6)
     # jitted reference path: XLA dot merging reassociates last-ulp only
-    x_jit = solver.solve(b, mode="reference")
+    x_jit = _execute_finalized(solver.finalized, b)
     np.testing.assert_allclose(np.asarray(x_flat), np.asarray(x_jit),
                                rtol=1e-5, atol=1e-6)
 
@@ -78,13 +80,13 @@ def test_finalized_multi_rhs_bitwise_and_shapes():
     fplan = blockamc.compile_plan(blockamc.build_plan(a, KN, cfg,
                                                       stages=stages))
     solver = blockamc.ProgrammedSolver.from_plan(fplan, cfg)
-    xs_fin = solver.solve(bs, jit=False, mode="reference")
+    xs_fin = blockamc.execute_finalized(solver.finalized, bs)
     assert xs_fin.shape == (n, k)
     np.testing.assert_array_equal(
         np.asarray(blockamc.execute_flat(fplan, bs, cfg)),
         np.asarray(xs_fin))
     np.testing.assert_allclose(
-        np.asarray(solver.solve_many(bs, mode="reference")),
+        np.asarray(_execute_finalized(solver.finalized, bs)),
         np.asarray(xs_fin), rtol=1e-5, atol=1e-6)
     # the serving-default fused path solves the same system (float tol;
     # pinned more tightly in tests/test_fused_arena.py)
@@ -137,7 +139,7 @@ def test_partition_program_split_matches_fused_build():
 
 def test_solve_batched_sharded_matches_batched():
     """shard_map path (1-device mesh here) == plain vmapped solve_batched."""
-    from repro.launch.mesh import make_mc_mesh
+    from repro.core.mc_sharding import make_mc_mesh
     n = 32
     cfg = AnalogConfig(array_size=16, nonideal=NonidealConfig(sigma=0.05))
     a = wishart(KA, n)
